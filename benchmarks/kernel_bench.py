@@ -1,5 +1,7 @@
-"""Kernel micro-benchmarks on this host (XLA path wall-clock; the Pallas
-path is TPU-target and validated via interpret mode in tests).
+"""Kernel micro-benchmarks of the XLA path, wall-clock on the host it runs
+on. The Pallas kernels are checked in interpret mode (tests/test_kernels.py),
+compiled for a described TPU v5e (tests/test_tpu_compile.py) and run on the
+chip against their oracles by chip_smoke.py; none of that is timed here.
 
 name, us_per_call, derived GFLOP/s.
 """

@@ -141,6 +141,8 @@ class BurstyArrivals(ArrivalProcess):
         until ``n`` arrivals have been generated."""
         assert self.on_rate_rps > 0, self.on_rate_rps
         assert self.off_rate_rps >= 0, self.off_rate_rps
+        # zero-length bursts emit nothing, so the walk below would never end
+        assert self.mean_on_ms > 0, self.mean_on_ms
         rng = np.random.default_rng(self.seed)
         out = np.empty(n, dtype=np.float64)
         got = 0

@@ -10,7 +10,9 @@ Supports causal masking and sliding-window masking (``window > 0``); the
 non-causal path serves the Whisper encoder.
 
 Validated on CPU via ``interpret=True`` against ``ref.attention_ref``
-(see tests/test_kernels.py).
+(tests/test_kernels.py); compiled for a described TPU v5e at qwen2.5-3b
+prefill shapes in tests/test_tpu_compile.py; run on the chip against the
+oracle by chip_smoke.py.
 """
 
 from __future__ import annotations

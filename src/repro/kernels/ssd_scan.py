@@ -8,7 +8,17 @@ analogue of the paper's SM-resident state; chunk = 256 keeps the
 (chunk x chunk) gate matrix and operand tiles inside VMEM and the matmuls
 MXU-aligned.
 
-Validated on CPU via ``interpret=True`` against ``ref.ssd_sequential``.
+Layout: the wrapper puts heads (groups) before length, so every block's two
+minor dims are (chunk, P), (chunk, N) or (chunk, 1) — a multiple of 8 rows
+by the full minor width, as the TPU's (8, 128) tiling requires. The step
+sizes arrive as a (chunk, 1) column; the within-chunk cumulative decay is
+formed from masked sums in f32 (no cumsum primitive, no MXU rounding). The
+per-head decay rate is a scalar in SMEM.
+
+Validated on CPU via ``interpret=True`` against ``ref.ssd_sequential``
+(tests/test_kernels.py); compiled for a described TPU v5e at mamba2-130m
+widths in tests/test_tpu_compile.py; run on the chip against the oracle by
+chip_smoke.py.
 """
 
 from __future__ import annotations
@@ -28,6 +38,7 @@ def _ssd_kernel(
     *,
     chunk: int,
 ):
+    hi = pl.program_id(1)
     ci = pl.program_id(2)
     nc = pl.num_programs(2)
 
@@ -35,33 +46,37 @@ def _ssd_kernel(
     def _init():
         h_scr[...] = jnp.zeros(h_scr.shape, h_scr.dtype)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)        # (Q, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)         # (Q,)
-    a = a_ref[0, 0].astype(jnp.float32)              # scalar
-    bm = b_ref[0, :, 0, :].astype(jnp.float32)       # (Q, N)
-    cm = c_ref[0, :, 0, :].astype(jnp.float32)       # (Q, N)
+    x = x_ref[0, 0].astype(jnp.float32)              # (Q, P)
+    dt = dt_ref[0, 0].astype(jnp.float32)            # (Q, 1)
+    a = a_ref[hi]                                    # scalar (SMEM)
+    bm = b_ref[0, 0].astype(jnp.float32)             # (Q, N)
+    cm = c_ref[0, 0].astype(jnp.float32)             # (Q, N)
 
-    lcum = jnp.cumsum(dt * a)                        # (Q,) inclusive, <= 0 terms
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tri = row >= col
+    da = jnp.broadcast_to(dt * a, (chunk, chunk))    # [r, t] = dt_r * a
+    # inclusive cumsum within the chunk, as a row then as a column
+    lrow = jnp.sum(jnp.where(row <= col, da, 0.0), axis=0, keepdims=True)  # (1, Q)
+    lcol = jnp.sum(jnp.where(row == col, jnp.broadcast_to(lrow, (chunk, chunk)),
+                             0.0), axis=1, keepdims=True)              # (Q, 1)
+
     # intra-chunk quadratic term
     cb = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)     # (Q, Q)
-    decay = jnp.exp(lcum[:, None] - lcum[None, :])                   # (Q, Q)
-    tri = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    gate = jnp.where(tri, cb * decay, 0.0)
-    xdt = x * dt[:, None]                                            # (Q, P)
-    y = jax.lax.dot_general(gate, xdt, (((1,), (0,)), ((), ())),
+    gate = jnp.where(tri, cb * jnp.exp(lcol - lrow), 0.0)
+    y = jax.lax.dot_general(gate, x * dt, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)      # (Q, P)
     # inter-chunk: contribution of carried state
     h = h_scr[...]                                                   # (P, N)
-    y += jnp.exp(lcum)[:, None] * jax.lax.dot_general(
+    y += jnp.exp(lcol) * jax.lax.dot_general(
         cm, h, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
+    y_ref[0, 0] = y.astype(y_ref.dtype)
     # state update: h <- exp(ltot) h + sum_t exp(ltot - l_t) dt_t x_t B_t^T
-    ltot = lcum[-1]
-    w = jnp.exp(ltot - lcum) * dt                                    # (Q,)
+    ltot = jnp.sum(dt * a, axis=0, keepdims=True)                    # (1, 1)
+    w = jnp.exp(ltot - lcol) * dt                                    # (Q, 1)
     h_scr[...] = h * jnp.exp(ltot) + jax.lax.dot_general(
-        x * w[:, None], bm, (((0,), (0,)), ((), ())),
+        (x * w).T, bm, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                          # (P, N)
 
     @pl.when(ci == nc - 1)
@@ -89,28 +104,31 @@ def ssd_scan(
     G, N = b_mat.shape[2], b_mat.shape[3]
     assert L % chunk == 0, f"L={L} % chunk={chunk}"
     nc = L // chunk
-    a2 = a.reshape(H, 1)
+    xt = jnp.swapaxes(x, 1, 2)                                   # (B, H, L, P)
+    dtt = jnp.swapaxes(dt, 1, 2)[..., None]                      # (B, H, L, 1)
+    bt = jnp.swapaxes(b_mat, 1, 2)                               # (B, G, L, N)
+    ct = jnp.swapaxes(c_mat, 1, 2)
 
     grid = (B, H, nc)
     y, h = pl.pallas_call(
         functools.partial(_ssd_kernel, chunk=chunk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, P), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1, 1), lambda b, h, c: (h, 0)),
-            pl.BlockSpec((1, chunk, 1, N), lambda b, h, c, G=G, H=H: (b, c, h * G // H, 0)),
-            pl.BlockSpec((1, chunk, 1, N), lambda b, h, c, G=G, H=H: (b, c, h * G // H, 0)),
+            pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, chunk, N), lambda b, h, c, G=G, H=H: (b, h * G // H, c, 0)),
+            pl.BlockSpec((1, 1, chunk, N), lambda b, h, c, G=G, H=H: (b, h * G // H, c, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, P), lambda b, h, c: (b, c, h, 0)),
+            pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct((B, H, L, P), x.dtype),
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(x, dt, a2, b_mat, c_mat)
-    return y, h
+    )(xt, dtt, a.astype(jnp.float32), bt, ct)
+    return jnp.swapaxes(y, 1, 2), h

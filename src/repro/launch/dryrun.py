@@ -1,15 +1,17 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
+os.environ["JAX_PLATFORMS"] = "cpu"   # the 512 placeholders are host devices
 
 """Multi-pod dry-run: lower + compile every (arch x input-shape x mesh).
 
 MUST be executed as its own process (``python -m repro.launch.dryrun``):
 the XLA_FLAGS line above runs before any other import so the 512 placeholder
-devices exist before jax locks the device count. Nothing here allocates
-real buffers — parameters, optimizer state and caches are ShapeDtypeStructs;
-``.compile()`` produces the SPMD executable whose memory/cost analyses and
-HLO feed EXPERIMENTS.md §Dry-run/§Roofline.
+devices exist before jax locks the device count, and the platform is pinned
+to the CPU so a machine with an accelerator still lowers onto them. Nothing
+here allocates real buffers — parameters, optimizer state and caches are
+ShapeDtypeStructs; ``.compile()`` produces the SPMD executable whose
+memory/cost analyses and HLO feed EXPERIMENTS.md §Dry-run/§Roofline.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen2-7b --shape train_4k

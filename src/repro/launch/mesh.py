@@ -20,10 +20,3 @@ def make_production_mesh(*, multi_pod: bool = False):
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
-
-def make_host_mesh(model_parallel: int = 1):
-    """Mesh over whatever devices exist (smoke tests / examples)."""
-    n = len(jax.devices())
-    assert n % model_parallel == 0
-    return jax.make_mesh((n // model_parallel, model_parallel), ("data", "model"),
-                         axis_types=_auto(2))

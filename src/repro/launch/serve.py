@@ -1,19 +1,32 @@
-"""Serving launcher: ``python -m repro.launch.serve --arch <id>``.
+"""Serving launcher: ``python -m repro.launch.serve --arch <id> [--full]``.
 
-Reduced model, AMP4EC-scheduled batched serving on the simulated edge
-cluster (see examples/serve_adaptive.py for the scripted adaptation demo).
+AMP4EC-scheduled batched serving with real greedy decode on the simulated
+edge cluster: the reduced model by default, the published widths with
+``--full`` (one accelerator chip; see chip_smoke.py). The scripted
+adaptation demo is examples/serve_adaptive.py.
 """
 
 from __future__ import annotations
 
 import argparse
 
+import jax
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
+from repro.configs.base import ModelConfig
 from repro.core.cluster import make_paper_cluster
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.serving import Request, ServingEngine
+
+
+def build_engine(cfg: ModelConfig, *, max_batch: int = 4,
+                 seed: int = 0) -> ServingEngine:
+    """Seeded weights for ``cfg`` behind a ServingEngine on the paper's
+    three-node cluster."""
+    params, _ = Model(cfg).init(jax.random.PRNGKey(seed))
+    return ServingEngine(cfg, params, make_paper_cluster(), max_batch=max_batch)
 
 
 def main():
@@ -23,13 +36,15 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=8)
     ap.add_argument("--new-tokens", type=int, default=8)
     ap.add_argument("--max-batch", type=int, default=4)
+    ap.add_argument("--full", action="store_true",
+                    help="published widths (one accelerator chip); default reduced")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch).reduced()
-    model = Model(cfg)
-    params, _ = model.init()
-    cluster = make_paper_cluster()
-    engine = ServingEngine(cfg, params, cluster, max_batch=args.max_batch)
+    enable_compile_cache()
+    cfg = get_config(args.arch)
+    if not args.full:
+        cfg = cfg.reduced()
+    engine = build_engine(cfg, max_batch=args.max_batch)
     reqs = [Request(i, np.arange(1, args.prompt_len + 1, dtype=np.int32),
                     args.new_tokens) for i in range(args.requests)]
     m = engine.serve(reqs)

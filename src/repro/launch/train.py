@@ -1,8 +1,7 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> ...``.
 
-On this CPU container it runs reduced configs on the host mesh; on a real
-pod the same entry point drives the production mesh (--mesh pod1/pod2 uses
-the 16x16 / 2x16x16 layouts with the dry-run's shardings).
+Runs the reduced config by default and the published widths with
+``--full``, on the default device.
 """
 
 from __future__ import annotations
@@ -13,6 +12,7 @@ import jax
 
 from repro.configs import ARCH_IDS, get_config
 from repro.data import DataConfig, batches_for_model
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.optim import adamw, cosine_with_warmup
 from repro.train import train
@@ -26,10 +26,11 @@ def main():
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--full", action="store_true",
-                    help="full config (requires a real pod); default reduced")
+                    help="published widths (needs an accelerator); default reduced")
     ap.add_argument("--ckpt-dir", default="")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if not args.full:
         cfg = cfg.reduced()

@@ -88,6 +88,7 @@ def _stack_init(rng, n: int, fn, abstract: bool, dtype):
         b = ParamBuilder(jax.random.fold_in(rng, i), dtype=dtype)
         fn(b)
         outs.append(b.build())
+    del b   # stack_layers frees each layer's arrays once nothing else holds them
     from repro.utils.params import stack_layers
     return stack_layers(outs)
 

@@ -20,11 +20,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.utils.params import ParamBuilder
-from repro.utils.sharding import current_rules, shard_map_compat as shard_map
+from repro.utils.sharding import current_rules
 
 
 def init_moe(b: ParamBuilder, name: str, cfg: ModelConfig):

@@ -23,12 +23,12 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.kernels import ops
 from repro.models.layers import apply_rope
-from repro.utils.sharding import shard_map_compat as shard_map
 
 
 def _norm(scale, x, eps):

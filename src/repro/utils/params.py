@@ -76,14 +76,31 @@ class ParamBuilder:
         return self.params, self.specs
 
 
+_stack_jit = jax.jit(lambda *xs: jnp.stack(xs, axis=0))
+
+
 def stack_layers(per_layer: list):
     """Stack a list of identical-structure (params, specs) into scanned params.
 
     Arrays gain a leading layer axis; specs gain a leading "layers" entry.
+    The list is consumed: each parameter's per-layer arrays are dropped as
+    soon as they are stacked, so the layer list and the stacked copy are
+    never both held whole.
     """
-    params_list = [p for p, _ in per_layer]
     specs = per_layer[0][1]
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *params_list)
+    treedef = jax.tree.structure(per_layer[0][0])
+    flat = [jax.tree.leaves(p) for p, _ in per_layer]
+    per_layer.clear()
+    stacked = []
+    for j in range(len(flat[0])):
+        # jitted so the stack writes its output directly (eager jnp.stack
+        # copies every input first), and waited on so the inputs are freed
+        # before the next parameter's stack is dispatched
+        stacked.append(jax.block_until_ready(
+            _stack_jit(*[leaves[j] for leaves in flat])))
+        for leaves in flat:
+            leaves[j] = None
+    stacked = jax.tree.unflatten(treedef, stacked)
     specs = jax.tree.map(
         lambda axes: ("layers",) + tuple(axes),
         specs,

@@ -16,20 +16,6 @@ from typing import Any, Mapping, Optional, Sequence, Tuple, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:    # jax <= 0.5.x: shard_map lives in experimental and takes check_rep=
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _SHARD_MAP_CHECK_KW = "check_rep"
-except ImportError:   # newer jax: top-level, check_rep renamed to check_vma
-    from jax import shard_map as _shard_map_impl
-    _SHARD_MAP_CHECK_KW = "check_vma"
-
-
-def shard_map_compat(body, *, mesh, in_specs, out_specs, check_vma=False):
-    """shard_map across jax versions (check_rep was renamed to check_vma)."""
-    return _shard_map_impl(body, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs,
-                           **{_SHARD_MAP_CHECK_KW: check_vma})
-
 LogicalAxis = Optional[str]
 Axes = Tuple[LogicalAxis, ...]
 

@@ -5,103 +5,13 @@ import os
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import dataclasses
-import functools
-import random
 
 import jax
-import numpy as np
 import pytest
+# re-exported: test modules import these via `from conftest import ...`
+from hypothesis import given, settings, strategies as st  # noqa: F401
 
 from repro.configs import get_config
-
-# --- property-testing shim ---------------------------------------------------
-# The container may lack `hypothesis`; the suite's property tests then fall
-# back to a deterministic random sampler with the same decorator surface
-# (given / settings / strategies). Test modules import these via
-# `from conftest import given, settings, st`.
-
-#: qualnames of tests that executed on the deterministic fallback sampler
-#: this session (empty when real `hypothesis` was importable) — reported in
-#: the terminal summary so a green run says which tests had shim coverage
-SHIM_SAMPLED_TESTS: set = set()
-
-
-class ShimReproduction(AssertionError):
-    """A shim-sampled property test failed; the message carries the
-    reproduction recipe (sampler seed + example index + drawn arguments),
-    since the fallback sampler has no shrinking or example database."""
-
-
-try:
-    from hypothesis import given, settings, strategies as st  # noqa: F401
-except ModuleNotFoundError:
-    class _Strategy:
-        def __init__(self, sample):
-            self.sample = sample
-
-    class _Strategies:
-        @staticmethod
-        def floats(min_value=0.0, max_value=1.0, allow_nan=False,
-                   allow_infinity=False):
-            return _Strategy(lambda r: r.uniform(min_value, max_value))
-
-        @staticmethod
-        def integers(min_value, max_value):
-            return _Strategy(lambda r: r.randint(min_value, max_value))
-
-        @staticmethod
-        def lists(elements, min_size=0, max_size=10):
-            def sample(r):
-                k = r.randint(min_size, max_size)
-                return [elements.sample(r) for _ in range(k)]
-            return _Strategy(sample)
-
-    st = _Strategies()
-
-    def settings(max_examples=25, deadline=None, **_kw):
-        def deco(fn):
-            fn._prop_max_examples = max_examples
-            return fn
-        return deco
-
-    def given(**strategy_kwargs):
-        def deco(fn):
-            n_examples = getattr(fn, "_prop_max_examples", 25)
-            seed = fn.__qualname__   # the sampler seed IS the qualname
-
-            @functools.wraps(fn)
-            def wrapper():
-                SHIM_SAMPLED_TESTS.add(seed)
-                rnd = random.Random(seed)
-                for i in range(n_examples):
-                    kwargs = {k: s.sample(rnd)
-                              for k, s in strategy_kwargs.items()}
-                    try:
-                        fn(**kwargs)
-                    except Exception as e:
-                        # no shrinking/database in the shim: the seed +
-                        # example index replays the exact draw
-                        raise ShimReproduction(
-                            f"shim-sampled property test failed — "
-                            f"reproduce with random.Random({seed!r}), "
-                            f"example index {i} of {n_examples}; "
-                            f"drawn args: {kwargs!r}") from e
-
-            del wrapper.__wrapped__  # keep pytest from seeing fn's params
-            return wrapper
-        return deco
-
-
-def pytest_terminal_summary(terminalreporter, exitstatus, config):
-    """Say which property tests ran on the deterministic fallback sampler
-    (no-op when real `hypothesis` did the sampling), so a green run is
-    explicit about the reduced generative coverage."""
-    if SHIM_SAMPLED_TESTS:
-        terminalreporter.write_sep(
-            "-", f"{len(SHIM_SAMPLED_TESTS)} property test(s) ran on the "
-                 f"deterministic hypothesis-fallback sampler")
-        for name in sorted(SHIM_SAMPLED_TESTS):
-            terminalreporter.write_line(f"  shim-sampled: {name}")
 
 
 @pytest.fixture(scope="session")

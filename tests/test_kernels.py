@@ -130,7 +130,8 @@ def test_ops_dispatch_modes():
         ops.attention(q, k, v, impl="bogus")
 
 
-RGLRU_SHAPES = [(1, 128, 64, 64), (2, 256, 128, 128), (1, 512, 96, 256)]
+RGLRU_SHAPES = [(1, 128, 64, 64), (2, 256, 128, 128), (1, 512, 96, 256),
+                (1, 128, 1024, 64)]   # two 512-lane width tiles
 
 
 @pytest.mark.parametrize("shape", RGLRU_SHAPES)

@@ -1,7 +1,7 @@
 """Sharded-MoE equivalence: expert-parallel shard_map paths vs local math.
 
-Runs in a subprocess with 8 fake devices (XLA_FLAGS must precede jax init,
-which pytest's process has already done), asserting:
+Runs in a CPU-pinned subprocess with 8 fake devices (XLA_FLAGS must precede
+jax init, which pytest's process has already done), asserting:
   - standard expert-parallel apply_moe  == local (no-mesh) apply_moe
   - weight-resident 2D apply_moe_2d     == local apply_moe
 in the drop-free regime (high capacity factor).
@@ -37,11 +37,8 @@ SCRIPT = textwrap.dedent("""
 
     y_local, aux_local = MOE.apply_moe(p, x, cfg)          # no mesh: local path
 
-    try:
-        mesh = jax.make_mesh((2, 4), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    except (AttributeError, TypeError):   # older jax: no axis_types kwarg
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     with logical_rules(mesh):
         y_ep, aux_ep = jax.jit(lambda p, x: MOE.apply_moe(p, x, cfg))(p, x)
     np.testing.assert_allclose(np.asarray(y_ep), np.asarray(y_local),
@@ -62,7 +59,7 @@ SCRIPT = textwrap.dedent("""
 def test_sharded_moe_paths_match_local():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"   # 8 fake host devices, never an accelerator
     proc = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                           capture_output=True, text=True, timeout=860)
     assert proc.returncode == 0, proc.stderr[-2000:]
@@ -87,11 +84,8 @@ SMBLOCK_SCRIPT = textwrap.dedent("""
     params, _ = m.init(jax.random.PRNGKey(0))
     batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0,
                                           cfg.vocab_size)}
-    try:
-        mesh = jax.make_mesh((2, 4), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    except (AttributeError, TypeError):   # older jax: no axis_types kwarg
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
     with logical_rules(mesh, {"seq": ("model",)}):
         ref_logits, _, _ = jax.jit(
@@ -122,7 +116,7 @@ SMBLOCK_SCRIPT = textwrap.dedent("""
 def test_shardmap_dense_block_matches_gspmd():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"   # 8 fake host devices, never an accelerator
     proc = subprocess.run([sys.executable, "-c", SMBLOCK_SCRIPT], env=env,
                           capture_output=True, text=True, timeout=860)
     assert proc.returncode == 0, proc.stderr[-2000:]

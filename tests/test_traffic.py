@@ -4,8 +4,7 @@ SLO metrics, adaptive micro-batching, and the overload drift trigger.
 
 The generative suite (``test_generative_*``) samples >200 configurations
 (cluster shape x arrival process x transfer model x fabric x micro-batch x
-seed) through the deterministic property-test shim in ``conftest.py`` and
-asserts *structural* invariants rather than pinned numbers — the contract
+seed) through ``hypothesis`` and asserts *structural* invariants rather than pinned numbers — the contract
 every future engine change must keep.
 """
 
@@ -56,9 +55,12 @@ def _arrival_process(kind: int, gap_ms: float, seed: int):
     if kind == 1:
         return PoissonArrivals(rate_rps=1000.0 / max(gap_ms, 1.0), seed=seed)
     if kind == 2:
+        # dwell means floored like the rate: bursts far shorter than the
+        # arrival gap (gap_ms near 0) emit almost nothing, and the on/off
+        # walk then takes unboundedly many dwells to produce n_req arrivals
         return BurstyArrivals(on_rate_rps=2000.0 / max(gap_ms, 1.0),
-                              mean_on_ms=5 * gap_ms, mean_off_ms=5 * gap_ms,
-                              seed=seed)
+                              mean_on_ms=5 * max(gap_ms, 1.0),
+                              mean_off_ms=5 * max(gap_ms, 1.0), seed=seed)
     base = DeterministicArrivals(gap_ms).offsets(8)     # short trace, looped
     return TraceArrivals(base + (seed % 7))
 
@@ -568,6 +570,14 @@ def test_bursty_is_burstier_than_poisson():
     cv = float(gaps.std() / gaps.mean())
     assert cv > 1.3, f"CV {cv} not bursty"
     assert bool(np.all(gaps >= 0))
+
+
+def test_bursty_refuses_zero_length_bursts():
+    """Bursts of zero mean length emit nothing; offsets() must refuse them
+    instead of walking the on/off chain forever."""
+    b = BurstyArrivals(on_rate_rps=2000.0, mean_on_ms=0.0, mean_off_ms=0.0)
+    with pytest.raises(AssertionError):
+        b.offsets(4)
 
 
 def test_trace_arrivals_file_roundtrip(tmp_path):

@@ -11,13 +11,9 @@ from repro.utils.sharding import (DEFAULT_RULES, LogicalRules, logical_rules,
                                   safe_sharding_tree, shard)
 
 
-def make_mesh_compat(shape, names):
-    """jax.make_mesh across versions: axis_types only exists in newer jax."""
-    try:
-        return jax.make_mesh(shape, names,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(names))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, names)
+def make_mesh(shape, names):
+    return jax.make_mesh(shape, names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
 
 SAMPLE_HLO = """
@@ -53,13 +49,12 @@ def test_op_histogram():
 
 
 def _norm(spec):
-    """PartitionSpec entries tuple-normalized: newer jax treats 'x' and
-    ('x',) as equal, older jax does not."""
+    """PartitionSpec entries tuple-normalized ('x' -> ('x',))."""
     return tuple((p,) if isinstance(p, str) else p for p in spec)
 
 
 def test_logical_rules_to_spec():
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = LogicalRules(mesh, DEFAULT_RULES)
     assert _norm(rules.to_spec(("batch", None, "heads"))) == \
         (("data",), None, ("model",))
@@ -73,7 +68,7 @@ def test_shard_noop_without_rules():
 
 
 def test_safe_sharding_drops_nondivisible():
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with logical_rules(mesh):
         arg = jax.ShapeDtypeStruct((5, 8), jnp.float32)   # 5 % 1 == 0 trivially
         sh = safe_sharding_tree((arg,), (("heads", "ff"),))
@@ -82,7 +77,7 @@ def test_safe_sharding_drops_nondivisible():
 
 
 def test_safe_sharding_nondivisible_dim_dropped():
-    mesh = make_mesh_compat((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     with logical_rules(mesh):
         arg = jax.ShapeDtypeStruct((24, 7), jnp.float32)
         (s,) = safe_sharding_tree((arg,), (("heads", "vocab"),))
@@ -111,3 +106,19 @@ def test_digest_is_content_sensitive():
     c = np.arange(8) + 1
     assert digest(a) == digest(b) != digest(c)
     assert digest(a.reshape(2, 4)) != digest(a)
+
+
+def test_stack_layers_consumes_list_and_keeps_values():
+    from repro.utils.params import ParamBuilder, stack_layers
+    per_layer = []
+    for i in range(3):
+        b = ParamBuilder(jax.random.PRNGKey(i), dtype=jnp.float32)
+        b.param("w", (4, 8), (None, "ff"))
+        b.sub("ln").param("scale", (8,), (None,), init="ones")
+        per_layer.append(b.build())
+    expect = jnp.stack([p["w"] for p, _ in per_layer])
+    params, specs = stack_layers(per_layer)
+    assert per_layer == []            # per-layer arrays released as stacked
+    assert (params["w"] == expect).all()
+    assert params["ln"]["scale"].shape == (3, 8)
+    assert specs == {"w": ("layers", None, "ff"), "ln": {"scale": ("layers", None)}}
