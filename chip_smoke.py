@@ -13,9 +13,14 @@ to the process that opened it) and fails the run if its check fails:
    checked against the monolithic forward on the chip (``verify_numerics``)
    and against a float32 forward on the host CPU. A short simulated stream
    then runs through ``DistributedInference.run`` in the same process.
+   The program's recorder (``repro.utils.obs``) is on for the plan and the
+   infer calls: the plan's time and each stage's warm time come from the
+   ``amp4ec.plan`` and ``amp4ec.stage`` spans.
 3. serving: qwen2.5-3b at published widths behind ``ServingEngine`` (the
    ``repro.launch.serve --full`` path): a few seeded requests with real
-   greedy decode, served twice; the tokens must agree.
+   greedy decode, served twice; the tokens must agree. The recorder is on
+   for a third, warm serve: its time to first token, gap between tokens and
+   routing time come from the spans (``serving.engine.measured_ms``).
 4. prefill: one jitted 1x2048 prefill with the Pallas flash-attention kernel
    and one with the XLA path; their last-position logits must agree.
 
@@ -27,6 +32,7 @@ before any phase. The last line of stdout is the JSON result.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import sys
@@ -52,6 +58,8 @@ from repro.models.model import Model  # noqa: E402
 from repro.models.rglru import lru_width  # noqa: E402
 from repro.models.ssm import ssm_dims  # noqa: E402
 from repro.serving import Request  # noqa: E402
+from repro.serving.engine import measured_ms  # noqa: E402
+from repro.utils import obs  # noqa: E402
 
 # Every error below is max|out - reference| / max|reference|.
 #: chip vs host float32 MobileNetV2: XLA on the TPU runs f32 convolutions
@@ -85,6 +93,19 @@ def _timed(fn, *args):
     t0 = time.perf_counter()
     out = jax.block_until_ready(fn(*args))
     return out, first, time.perf_counter() - t0
+
+
+@contextlib.contextmanager
+def _recording():
+    """The program's recorder on for the block; yields the list that
+    holds its spans once the block ends."""
+    spans = []
+    obs.enable()
+    try:
+        yield spans
+    finally:
+        obs.disable()
+        spans += obs.snapshot()["spans"]
 
 
 def _peak_gb() -> str:
@@ -161,18 +182,25 @@ def phase_partitioned(batch: int = 8, image: int = MNV2.IMAGE_SIZE,
     """Plan, verify and serve MobileNetV2 through the partitioned path."""
     dev = jax.devices()[0]
     leaves = build_mobilenetv2(jax.random.PRNGKey(seed))
-    d = DistributedInference(
-        make_paper_cluster(), ModelPartitioner(mobilenetv2_graph()),
-        method="planner", batch=batch,
-        executor=lambda lo, hi, x, res: run_range(leaves, lo, hi, x, res))
-    print(f"partitioned: plan {d.plan.sizes} on {d.placement}")
+    with _recording() as spans:
+        d = DistributedInference(
+            make_paper_cluster(), ModelPartitioner(mobilenetv2_graph()),
+            method="planner", batch=batch,
+            executor=lambda lo, hi, x, res: run_range(leaves, lo, hi, x, res))
+    plan_ms = sum(s.end - s.start for s in spans if s.name == "amp4ec.plan") * 1e3
+    print(f"partitioned: plan {d.plan.sizes} on {d.placement} in {plan_ms:.3f} ms")
     x = jax.random.normal(jax.random.PRNGKey(seed + 1), (batch, image, image, 3))
     t0 = time.perf_counter()
     _check(d.verify_numerics(x), "partitioned forward != monolithic forward on chip")
     print(f"partitioned == monolithic on {dev.device_kind} "
           f"(rtol 1e-5, atol 1e-5): {time.perf_counter() - t0:.3f} s incl. compile")
 
-    y, first, warm = _timed(d.infer, x)
+    with _recording() as spans:
+        y, first, warm = _timed(d.infer, x)
+    last = max(s.span_id for s in spans if s.name == "amp4ec.infer")
+    stage_ms = [(s.end - s.start) * 1e3 for s in spans
+                if s.name == "amp4ec.stage" and s.root_id == last]
+    print("partitioned warm stages (ms): " + ", ".join(f"{ms:.3f}" for ms in stage_ms))
     _check(y.shape == (batch, MNV2.NUM_CLASSES), f"output shape {y.shape}")
     _check(y.devices() == {dev}, f"stages ran on {y.devices()}, not {dev}")
     cpu = jax.devices("cpu")[0]
@@ -189,7 +217,8 @@ def phase_partitioned(batch: int = 8, image: int = MNV2.IMAGE_SIZE,
     _check(rep.done_count == stream, f"stream finished {rep.done_count}/{stream}")
     print(f"partitioned stream (simulated clock): {rep.done_count} requests, "
           f"{rep.throughput_rps:.3f} rps, avg latency {rep.avg_latency_ms:.3f} ms")
-    return dict(host_err=err, first_s=first, warm_s=warm)
+    return dict(host_err=err, first_s=first, warm_s=warm, plan_ms=plan_ms,
+                stage_ms=stage_ms)
 
 
 # ---------------------------------------------------------------------------
@@ -214,7 +243,9 @@ def phase_serving(cfg, *, requests: int = 4, prompt_len: int = 16,
         return np.stack([r.output for r in reqs])
 
     tokens, first, warm = _timed(serve)
-    again = serve()
+    with _recording() as spans:
+        again = serve()
+    measured = measured_ms({"spans": spans})
     _check(tokens.shape == (requests, new_tokens), f"tokens shape {tokens.shape}")
     _check(bool(((tokens >= 0) & (tokens < cfg.vocab_size)).all()),
            "token outside the vocabulary")
@@ -222,6 +253,8 @@ def phase_serving(cfg, *, requests: int = 4, prompt_len: int = 16,
     print(f"serving {requests} x ({prompt_len} prompt + {new_tokens} new): first "
           f"{first:.3f} s, warm {warm:.3f} s, tokens identical across serves, "
           f"peak {_peak_gb()}")
+    print("serving, measured on the host clock (ms): "
+          + ", ".join(f"{k} {v:.3f}" for k, v in measured.items()))
     print(f"serving tokens[0]: {tokens[0].tolist()}")
     return engine
 

@@ -27,17 +27,24 @@ from repro.models.graph import transformer_graph
 from repro.models.model import Model
 from repro.optim import adamw, cosine_with_warmup
 from repro.serving import Request, ServingEngine
+from repro.serving.engine import measured_ms
 from repro.train import train
+from repro.utils import obs
 
 
 def phase(engine, name, n_requests, start_id=0):
     reqs = [Request(start_id + i, np.arange(3, 11, dtype=np.int32) + (i % 4), 8)
             for i in range(n_requests)]
+    obs.enable()
     m = engine.serve(reqs)
-    print(f"  [{name}] {m['num_requests']} reqs | "
-          f"avg latency {m['avg_latency_ms']:.1f} ms | "
-          f"ttft {m['avg_ttft_ms']:.1f} ms | "
+    obs.disable()
+    t = measured_ms(obs.snapshot())
+    print(f"  [{name}] {m['num_requests']} reqs | simulated edge time: "
+          f"avg latency {m['avg_latency_ms']:.1f} ms, "
+          f"ttft {m['avg_ttft_ms']:.1f} ms, "
           f"{m['tokens_per_s']:.1f} tok/s | per-node {m['requests_per_node']}")
+    print(f"  [{name}] measured on the host clock: ttft {t['ttft_ms']:.1f} ms, "
+          f"inter-token {t['itl_ms']:.2f} ms, route {t['route_ms']:.3f} ms")
     return m
 
 

@@ -43,6 +43,7 @@ from repro.core.planner import (PartitionPlanner, PlannerConfig,
                                 node_views_from_cluster)
 from repro.core.scheduler import SCHEDULING_OVERHEAD_MS, TaskScheduler
 from repro.core.tenancy import Tenant
+from repro.utils import obs
 
 
 @dataclass
@@ -595,19 +596,24 @@ class DistributedInference:
         sig = (digest(x, signature=signature, memo=self.cache.digest_memo)
                if self.cache is not None else None)
         h, res = x, None
-        for part in self.plan.partitions:
-            key = None
-            if self.cache is not None:
-                key = self.cache.key(self.plan.graph_name,
-                                     (part.lo, part.hi), sig)
-                cached = self.cache.get(key)
-                if cached is not None:
-                    h, res = cached
-                    continue
-            h, res = self.executor(part.lo, part.hi, h, res)
-            if self.cache is not None:
-                self.cache.put(key, (h, res),
-                               transfer_bytes=part.out_bytes * self.batch)
+        with obs.root("amp4ec.infer"):
+            for part in self.plan.partitions:
+                key = None
+                if self.cache is not None:
+                    key = self.cache.key(self.plan.graph_name,
+                                         (part.lo, part.hi), sig)
+                    cached = self.cache.get(key)
+                    if cached is not None:
+                        h, res = cached
+                        continue
+                attrs = (dict(stage=part.index, lo=part.lo, hi=part.hi,
+                              node=self.placement.get(part.index))
+                         if obs.enabled() else {})
+                with obs.span("amp4ec.stage", **attrs):
+                    h, res = self.executor(part.lo, part.hi, h, res)
+                if self.cache is not None:
+                    self.cache.put(key, (h, res),
+                                   transfer_bytes=part.out_bytes * self.batch)
         return h
 
     # --- elasticity (beyond-paper: the paper fixes boundaries after deploy) ---

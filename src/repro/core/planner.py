@@ -62,7 +62,6 @@ from __future__ import annotations
 
 import itertools
 import math
-import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -75,6 +74,7 @@ from repro.core.cost_model import (ANALYTIC_BATCH_MODEL, BASE_THROUGHPUT,
                                    working_set_bytes)
 from repro.core.partitioner import bottleneck_boundaries
 from repro.models.graph import ModelGraph
+from repro.utils import obs
 
 _EPS = 1e-9
 
@@ -153,7 +153,6 @@ class PlanResult:
     bottleneck_ms: float
     mode: str
     dp_runs: int = 0
-    elapsed_ms: float = 0.0
     node_idx: List[int] = field(default_factory=list)   # internal indices
     moved_stages: int = 0
 
@@ -508,7 +507,6 @@ class PartitionPlanner:
             ``PlanResult`` with node ids filled in, or None when no node has
             capacity.
         """
-        t_start = time.perf_counter()
         views = [v for v in views if v.capability > 0.0]
         if not views:
             return None
@@ -516,44 +514,45 @@ class PartitionPlanner:
         if mode == "auto":
             mode = ("exhaustive"
                     if len(views) <= self.cfg.exhaustive_max_nodes else "dp")
-        n = len(views)
-        # one contiguous stage per node bounds dp/exhaustive at n stages;
-        # assign/beam may reuse nodes, so they are only capped by config
-        default_max = self._L if mode in ("beam", "assign") else n
-        max_stages = min(self._L, self.cfg.max_stages or default_max)
-        if mode not in ("beam", "assign"):
-            # clamp a configured max_stages to the LIVE node count: after a
-            # death, fewer nodes than the deploy-time stage count must yield
-            # a shallower plan, not an empty permutation search (-> None,
-            # which the controller would misread as "no capacity")
-            max_stages = min(max_stages, n)
-        scale = calibration * batch / speedup
-        tmats = [self._time_matrix(v, batch, scale, expected_k)
-                 for v in views]
-        if weight != 1.0:
-            tmats = [m * weight for m in tmats]
-        caps = [v.capability for v in views]
-        committed, floor = self._committed_vector(views, committed_ms)
+        with obs.span("amp4ec.plan", mode=mode, nodes=len(views)) as sp:
+            n = len(views)
+            # one contiguous stage per node bounds dp/exhaustive at n stages;
+            # assign/beam may reuse nodes, so they are only capped by config
+            default_max = self._L if mode in ("beam", "assign") else n
+            max_stages = min(self._L, self.cfg.max_stages or default_max)
+            if mode not in ("beam", "assign"):
+                # clamp a configured max_stages to the LIVE node count: after a
+                # death, fewer nodes than the deploy-time stage count must yield
+                # a shallower plan, not an empty permutation search (-> None,
+                # which the controller would misread as "no capacity")
+                max_stages = min(max_stages, n)
+            scale = calibration * batch / speedup
+            tmats = [self._time_matrix(v, batch, scale, expected_k)
+                     for v in views]
+            if weight != 1.0:
+                tmats = [m * weight for m in tmats]
+            caps = [v.capability for v in views]
+            committed, floor = self._committed_vector(views, committed_ms)
 
-        if mode == "beam":
-            res = self._beam(tmats, n, max_stages, committed)
-        elif mode == "assign":
-            res = self._assign(tmats, caps, max_stages, committed)
-        elif mode == "exhaustive":
-            res = self._search_orders(
-                itertools.permutations(range(n), max_stages),
-                self._with_committed(tmats, committed), mode)
-        elif mode == "dp":
-            res = self._dp_candidates(self._with_committed(tmats, committed),
-                                      caps, max_stages)
-        else:
-            raise ValueError(f"unknown planner mode: {mode}")
-        if res is None:
-            return None
-        res.bottleneck_ms = max(res.bottleneck_ms, floor)
-        res.assignment = [views[j].node_id for j in res.node_idx]
-        res.elapsed_ms = (time.perf_counter() - t_start) * 1e3
-        return res
+            if mode == "beam":
+                res = self._beam(tmats, n, max_stages, committed)
+            elif mode == "assign":
+                res = self._assign(tmats, caps, max_stages, committed)
+            elif mode == "exhaustive":
+                res = self._search_orders(
+                    itertools.permutations(range(n), max_stages),
+                    self._with_committed(tmats, committed), mode)
+            elif mode == "dp":
+                res = self._dp_candidates(self._with_committed(tmats, committed),
+                                          caps, max_stages)
+            else:
+                raise ValueError(f"unknown planner mode: {mode}")
+            if res is None:
+                return None
+            res.bottleneck_ms = max(res.bottleneck_ms, floor)
+            res.assignment = [views[j].node_id for j in res.node_idx]
+            sp.set(stages=res.stages)
+            return res
 
     @staticmethod
     def _committed_vector(views, committed_ms):
@@ -772,57 +771,57 @@ class PartitionPlanner:
         re-homed first and do not count against ``max_moves`` — repairing
         availability is not a voluntary move. Returns None when no finite
         assignment of the fixed cuts exists."""
-        t_start = time.perf_counter()
         views = [v for v in views if v.capability > 0.0]
         if not views:
             return None
-        scale = calibration * batch / speedup
-        tmats = [self._time_matrix(v, batch, scale, expected_k)
-                 for v in views]
-        if weight != 1.0:
-            tmats = [m * weight for m in tmats]
-        committed, floor = self._committed_vector(views, committed_ms)
-        n, m = len(views), len(cuts) - 1
-        t = np.array([[float(tm[cuts[i], cuts[i + 1]]) for i in range(m)]
-                      for tm in tmats])
-        idx_of = {v.node_id: j for j, v in enumerate(views)}
-        assign: List[int] = []
-        forced: List[int] = []
-        for i, nid in enumerate(assignment):
-            j = idx_of.get(nid)
-            if j is None:
-                forced.append(i)
-            assign.append(-1 if j is None else j)
-        loads = (np.zeros(n) if committed is None
-                 else np.asarray(committed, dtype=np.float64).copy())
-        for i, j in enumerate(assign):
-            if j >= 0:
+        with obs.span("amp4ec.plan", mode="partial", nodes=len(views),
+                      stages=len(cuts) - 1):
+            scale = calibration * batch / speedup
+            tmats = [self._time_matrix(v, batch, scale, expected_k)
+                     for v in views]
+            if weight != 1.0:
+                tmats = [m * weight for m in tmats]
+            committed, floor = self._committed_vector(views, committed_ms)
+            n, m = len(views), len(cuts) - 1
+            t = np.array([[float(tm[cuts[i], cuts[i + 1]]) for i in range(m)]
+                          for tm in tmats])
+            idx_of = {v.node_id: j for j, v in enumerate(views)}
+            assign: List[int] = []
+            forced: List[int] = []
+            for i, nid in enumerate(assignment):
+                j = idx_of.get(nid)
+                if j is None:
+                    forced.append(i)
+                assign.append(-1 if j is None else j)
+            loads = (np.zeros(n) if committed is None
+                     else np.asarray(committed, dtype=np.float64).copy())
+            for i, j in enumerate(assign):
+                if j >= 0:
+                    loads[j] += t[j, i]
+            for i in forced:                    # dead homes: re-home first
+                j = int(np.argmin(loads + t[:, i]))
+                if not math.isfinite(float(t[j, i])):
+                    return None
+                assign[i] = j
                 loads[j] += t[j, i]
-        for i in forced:                    # dead homes: re-home first
-            j = int(np.argmin(loads + t[:, i]))
-            if not math.isfinite(float(t[j, i])):
+            moved: set = set()
+            for _ in range(max_moves):
+                movable = [i for i in range(m)
+                           if i not in moved and i not in forced]
+                move = self._best_single_move(t, loads, assign, movable)
+                if move is None:
+                    break
+                i, j = move
+                loads[assign[i]] -= t[assign[i], i]
+                loads[j] += t[j, i]
+                assign[i] = j
+                moved.add(i)
+            bott = max(float(loads.max()), floor)
+            if not math.isfinite(bott):
                 return None
-            assign[i] = j
-            loads[j] += t[j, i]
-        moved: set = set()
-        for _ in range(max_moves):
-            movable = [i for i in range(m)
-                       if i not in moved and i not in forced]
-            move = self._best_single_move(t, loads, assign, movable)
-            if move is None:
-                break
-            i, j = move
-            loads[assign[i]] -= t[assign[i], i]
-            loads[j] += t[j, i]
-            assign[i] = j
-            moved.add(i)
-        bott = max(float(loads.max()), floor)
-        if not math.isfinite(bott):
-            return None
-        return PlanResult(list(cuts), [views[j].node_id for j in assign],
-                          bott, "partial", node_idx=assign,
-                          moved_stages=len(moved) + len(forced),
-                          elapsed_ms=(time.perf_counter() - t_start) * 1e3)
+            return PlanResult(list(cuts), [views[j].node_id for j in assign],
+                              bott, "partial", node_idx=assign,
+                              moved_stages=len(moved) + len(forced))
 
     # --- per-plan node loads (tenancy budgets) -------------------------------
 

@@ -2,8 +2,10 @@
 
 AMP4EC-scheduled batched serving with real greedy decode on the simulated
 edge cluster: the reduced model by default, the published widths with
-``--full`` (one accelerator chip; see chip_smoke.py). The scripted
-adaptation demo is examples/serve_adaptive.py.
+``--full`` (one accelerator chip; see chip_smoke.py). Prints the engine's
+metrics in simulated edge time, then the time to first token, the gap
+between tokens and the routing time measured from the program's spans.
+The scripted adaptation demo is examples/serve_adaptive.py.
 """
 
 from __future__ import annotations
@@ -19,6 +21,8 @@ from repro.core.cluster import make_paper_cluster
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.serving import Request, ServingEngine
+from repro.serving.engine import SIMULATED, measured_ms
+from repro.utils import obs
 
 
 def build_engine(cfg: ModelConfig, *, max_batch: int = 4,
@@ -47,10 +51,14 @@ def main():
     engine = build_engine(cfg, max_batch=args.max_batch)
     reqs = [Request(i, np.arange(1, args.prompt_len + 1, dtype=np.int32),
                     args.new_tokens) for i in range(args.requests)]
+    obs.enable()
     m = engine.serve(reqs)
+    obs.disable()
     for k, v in m.items():
         if k != "scheduler":
-            print(f"{k}: {v}")
+            print(f"{k}{' (simulated edge time)' if k in SIMULATED else ''}: {v}")
+    for k, v in measured_ms(obs.snapshot()).items():
+        print(f"{k} (measured on the host clock, compiles included): {v}")
 
 
 if __name__ == "__main__":

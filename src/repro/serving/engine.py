@@ -27,8 +27,37 @@ from repro.core.cluster import EdgeCluster
 from repro.core.monitor import ResourceMonitor
 from repro.core.scheduler import SCHEDULING_OVERHEAD_MS, TaskRequirements, TaskScheduler
 from repro.models.model import Model
+from repro.utils import obs
 
 EDGE_FLOPS_PER_CPU = 5e9  # effective flop/s per 1.0 edge CPU (serving cost model)
+
+
+# serve()'s metrics priced by the edge cost model, not timed
+SIMULATED = ("avg_latency_ms", "p99_latency_ms", "avg_ttft_ms", "tokens_per_s")
+
+
+def measured_ms(snapshot: dict) -> Dict[str, Optional[float]]:
+    """Host-clock times of ``serve`` calls, from a snapshot of the recorder
+    (``repro.utils.obs``): the mean time to a group's first token on the
+    host (``amp4ec.prompt``), the mean gap between its generated tokens
+    (``amp4ec.generate`` over new tokens less one) and the mean time to
+    route a group (``amp4ec.schedule``); None where nothing was recorded."""
+    spans = snapshot["spans"]
+
+    def mean(name):
+        found = [s.end - s.start for s in spans if s.name == name]
+        return sum(found) / len(found) * 1e3 if found else None
+
+    groups = {s.span_id: s for s in spans if s.name == "amp4ec.group"}
+    generate = [s for s in spans if s.name == "amp4ec.generate" and s.root_id in groups]
+    gaps = sum(groups[s.root_id].attrs["new_tokens"] - 1 for s in generate)
+    itl = sum(s.end - s.start for s in generate) / gaps * 1e3 if gaps > 0 else None
+    return dict(ttft_ms=mean("amp4ec.prompt"), itl_ms=itl, route_ms=mean("amp4ec.schedule"))
+
+
+def cache_len(prompt_len: int, new_tokens: int) -> int:
+    """Cache slots a group decodes into: every position, and one spare."""
+    return prompt_len + new_tokens + 1
 
 
 @dataclasses.dataclass
@@ -72,13 +101,16 @@ class ServingEngine:
     # --- generation -------------------------------------------------------------
 
     def _generate_group(self, group: List[Request]) -> np.ndarray:
-        """Real greedy decode for a uniform-length group. Returns (B, N)."""
+        """Real greedy decode for a uniform-length group. Returns (B, N).
+
+        ``amp4ec.prompt`` runs until the first generated token is on the
+        host, so the device backlog of the teacher-forced steps falls inside
+        it; ``amp4ec.generate`` runs from there until the last one is."""
         cfg = self.cfg
         B = len(group)
         P = len(group[0].prompt)
         N = group[0].max_new_tokens
-        cache_len = P + N + 1
-        cache, _ = self.model.init_cache(B, cache_len)
+        cache, _ = self.model.init_cache(B, cache_len(P, N))
         extras = {}
         if cfg.family == "audio":
             from repro.data.pipeline import frontend_stub
@@ -91,21 +123,38 @@ class ServingEngine:
 
         tokens = jnp.asarray(np.stack([r.prompt for r in group]), jnp.int32)
         out = []
-        tok = tokens[:, 0]
-        for t in range(P + N - 1):
-            logits, cache = self._decode_jit(self.params, tok, cache)
-            if t + 1 < P:
-                tok = tokens[:, t + 1]           # teacher-forced prompt
-            else:
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                out.append(np.asarray(tok))
+        with obs.span("amp4ec.prompt"):
+            for t in range(P - 1):               # teacher-forced prompt
+                _, cache = self._step(tokens[:, t], cache)
+            tok = tokens[:, P - 1]
+            if N:
+                tok, cache = self._next_token(tok, cache, out)
+        with obs.span("amp4ec.generate"):
+            for _ in range(N - 1):
+                tok, cache = self._next_token(tok, cache, out)
         return np.stack(out, axis=1) if out else np.zeros((B, 0), np.int32)
 
+    def _step(self, tok, cache):
+        with obs.span("amp4ec.step"):
+            return self._decode_jit(self.params, tok, cache)
+
+    def _next_token(self, tok, cache, out: list):
+        """One decode step and its greedy token, which is appended to
+        ``out`` on the host; returns the token on the device and the
+        cache."""
+        logits, cache = self._step(tok, cache)
+        with obs.span("amp4ec.sample"):
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            out.append(np.asarray(tok))
+        return tok, cache
 
     # --- serving ------------------------------------------------------------------
 
     def serve(self, requests: List[Request]) -> dict:
-        """Process all requests; returns aggregate metrics.
+        """Process all requests; returns aggregate metrics. The latencies,
+        ``avg_ttft_ms`` and ``tokens_per_s`` (the keys of ``SIMULATED``) are
+        simulated edge time from ``EDGE_FLOPS_PER_CPU``, not a clock; with
+        the recorder on, ``measured_ms`` gives the host clock's.
 
         All request groups are submitted at the current simulated time (a
         closed batch, like the paper's request batches); the NSA sees the
@@ -118,32 +167,39 @@ class ServingEngine:
             r.arrival_ms = max(r.arrival_ms, t0)
         groups = self._buckets(requests)
         done: List[tuple] = []
-        for group in groups:
-            stats = self.monitor.poll(force=True)
-            node_id = self.scheduler.select_node(
-                [s for s in stats.values() if s.online], TaskRequirements())
-            if node_id is None:
-                node_id = min(self.cluster.online_nodes(),
-                              key=lambda n: n.busy_until_ms).node_id
-            node = self.cluster.nodes[node_id]
-            out = self._generate_group(group)
+        with obs.span("amp4ec.serve", requests=len(requests)):
+            for group in groups:
+                P = len(group[0].prompt)
+                N = group[0].max_new_tokens
+                with obs.span("amp4ec.schedule"):
+                    stats = self.monitor.poll(force=True)
+                    node_id = self.scheduler.select_node(
+                        [s for s in stats.values() if s.online], TaskRequirements())
+                    if node_id is None:
+                        node_id = min(self.cluster.online_nodes(),
+                                      key=lambda n: n.busy_until_ms).node_id
+                attrs = (dict(batch=len(group), prompt_len=P, new_tokens=N,
+                              cache_len=cache_len(P, N), node=node_id,
+                              requests=[r.request_id for r in group])
+                         if obs.enabled() else {})
+                with obs.root("amp4ec.group", **attrs):
+                    out = self._generate_group(group)
 
-            P = len(group[0].prompt)
-            N = group[0].max_new_tokens
-            ms_per_token = (self._flops_per_token * len(group)
-                            / (EDGE_FLOPS_PER_CPU * node.profile.cpu) * 1e3)
-            start = max(t0 + SCHEDULING_OVERHEAD_MS, node.busy_until_ms)
-            ttft = start + P * ms_per_token
-            finish = start + (P + N) * ms_per_token
-            node.busy_until_ms = finish
-            node.task_count += 1
-            node.cpu_busy_ms += finish - start
-            done.append((node_id, finish - start))
-            for i, r in enumerate(group):
-                r.output = out[i]
-                r.node_id = node_id
-                r.ttft_ms = ttft - t0
-                r.finish_ms = finish
+                node = self.cluster.nodes[node_id]
+                ms_per_token = (self._flops_per_token * len(group)
+                                / (EDGE_FLOPS_PER_CPU * node.profile.cpu) * 1e3)
+                start = max(t0 + SCHEDULING_OVERHEAD_MS, node.busy_until_ms)
+                ttft = start + P * ms_per_token
+                finish = start + (P + N) * ms_per_token
+                node.busy_until_ms = finish
+                node.task_count += 1
+                node.cpu_busy_ms += finish - start
+                done.append((node_id, finish - start))
+                for i, r in enumerate(group):
+                    r.output = out[i]
+                    r.node_id = node_id
+                    r.ttft_ms = ttft - t0
+                    r.finish_ms = finish
         for node_id, dur in done:
             self.scheduler.task_completed(node_id, dur)
         clock.now_ms = max([clock.now_ms] + [r.finish_ms for r in requests])

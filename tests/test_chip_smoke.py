@@ -37,11 +37,17 @@ def test_partitioned_phase():
     out = chip_smoke.phase_partitioned(batch=2, image=32, stream=8)
     # host and "device" are the same CPU here: the reference agrees exactly
     assert out["host_err"] == 0.0
+    # read from the program's spans: the plan, and one warm call's stages
+    assert out["plan_ms"] > 0.0
+    assert len(out["stage_ms"]) >= 2 and min(out["stage_ms"]) > 0.0
 
 
-def test_serving_and_prefill_phases():
+def test_serving_and_prefill_phases(capsys):
     cfg = f32_reduced("qwen2.5-3b")
     engine = chip_smoke.phase_serving(cfg, requests=2, prompt_len=4, new_tokens=3)
+    measured = [ln for ln in capsys.readouterr().out.splitlines()
+                if ln.startswith("serving, measured")]
+    assert len(measured) == 1 and "ttft_ms" in measured[0] and "None" not in measured[0]
     err = chip_smoke.phase_prefill(cfg, engine.params, seq=128,
                                    impl="pallas_interpret")
     assert err < 1e-4
