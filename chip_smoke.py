@@ -19,8 +19,9 @@ to the process that opened it) and fails the run if its check fails:
 3. serving: qwen2.5-3b at published widths behind ``ServingEngine`` (the
    ``repro.launch.serve --full`` path): a few seeded requests with real
    greedy decode, served twice; the tokens must agree. The recorder is on
-   for a third, warm serve: its time to first token, gap between tokens and
-   routing time come from the spans (``serving.engine.measured_ms``).
+   for a third, warm serve: its time to first token, gap between tokens,
+   routing time and share of prompt positions prefilled come from the spans
+   (``serving.engine.measured_ms``).
 4. prefill: one jitted 1x2048 prefill with the Pallas flash-attention kernel
    and one with the XLA path; their last-position logits must agree.
 
@@ -253,8 +254,10 @@ def phase_serving(cfg, *, requests: int = 4, prompt_len: int = 16,
     print(f"serving {requests} x ({prompt_len} prompt + {new_tokens} new): first "
           f"{first:.3f} s, warm {warm:.3f} s, tokens identical across serves, "
           f"peak {_peak_gb()}")
+    share = measured.pop("prefill_share")
     print("serving, measured on the host clock (ms): "
-          + ", ".join(f"{k} {v:.3f}" for k, v in measured.items()))
+          + ", ".join(f"{k} {v:.3f}" for k, v in measured.items())
+          + f"; prefill_share {share}")
     print(f"serving tokens[0]: {tokens[0].tolist()}")
     return engine
 

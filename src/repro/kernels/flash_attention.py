@@ -75,6 +75,11 @@ def _flash_kernel(
     l_new = alpha * l_prev + p.sum(axis=1, keepdims=True)
 
     v = v_ref[0, 0].astype(jnp.float32)                      # (bk, d)
+    if seq_len % block_k:
+        # the last kv block reads past the end, where the values are
+        # undefined (NaN in interpret mode) and 0 * NaN would reach acc
+        v_row = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
+        v = jnp.where(v_row < seq_len, v, 0.0)
     pv = jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,

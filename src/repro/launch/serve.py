@@ -4,7 +4,8 @@ AMP4EC-scheduled batched serving with real greedy decode on the simulated
 edge cluster: the reduced model by default, the published widths with
 ``--full`` (one accelerator chip; see chip_smoke.py). Prints the engine's
 metrics in simulated edge time, then the time to first token, the gap
-between tokens and the routing time measured from the program's spans.
+between tokens and the routing time measured from the program's spans, and
+the share of prompt positions the engine prefilled rather than stepped.
 The scripted adaptation demo is examples/serve_adaptive.py.
 """
 
@@ -57,8 +58,11 @@ def main():
     for k, v in m.items():
         if k != "scheduler":
             print(f"{k}{' (simulated edge time)' if k in SIMULATED else ''}: {v}")
-    for k, v in measured_ms(obs.snapshot()).items():
+    measured = measured_ms(obs.snapshot())
+    share = measured.pop("prefill_share")
+    for k, v in measured.items():
         print(f"{k} (measured on the host clock, compiles included): {v}")
+    print(f"prefill_share (prompt positions taken by one prefill): {share}")
 
 
 if __name__ == "__main__":

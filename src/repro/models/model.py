@@ -3,8 +3,9 @@
 One ``Model`` object per ``ModelConfig`` exposes:
 
   init(rng, abstract)        -> (params, specs)
-  forward_train(params, batch)      -> (logits, aux_loss)
-  prefill(params, batch)            -> (last_logits, cache)
+  forward(params, batch, mode=)     -> (logits, aux_loss, prefill_kv_or_None)
+  loss_fn(params, batch)            -> (loss, nll)
+  prefill(params, tokens, cache_len) -> (last_logits, cache)   [where can_prefill]
   decode_step(params, token, cache [, memory_kv built into cache]) -> (logits, cache)
   init_cache(batch, cache_len, abstract) -> (cache, cache_specs)
   input_specs(shape_name)    -> kwargs of ShapeDtypeStructs for the step fns
@@ -592,6 +593,36 @@ class Model:
 
         put("pos", (), (), jnp.int32)
         return entries, specs
+
+    @property
+    def can_prefill(self) -> bool:
+        """Whether ``prefill`` fills the cache that stepping ``decode_step``
+        through the prompt would: a dense decoder with a plain k/v cache.
+        Elsewhere the full-sequence pass computes something else (moe
+        experts drop tokens at capacity, single steps never do), or the
+        cache has another layout (MLA, int8) or holds recurrent or cross
+        state."""
+        cfg = self.cfg
+        return cfg.family == "dense" and not cfg.use_mla and cfg.kv_cache_dtype != "int8"
+
+    def prefill(self, params, tokens: jax.Array, cache_len: int):
+        """The whole prompt in one full-sequence pass. tokens: (B, P) int32.
+
+        Returns (logits of the last position (B, V), the decode cache of
+        ``cache_len`` slots that ``init_cache`` describes, with the prompt's
+        k/v in slots [0, P), zeros after them and ``pos`` = P). Only where
+        ``can_prefill``."""
+        if not self.can_prefill:
+            raise ValueError(f"{self.cfg.name}: prefill needs a dense decoder "
+                             "with a plain k/v cache")
+        P = tokens.shape[1]
+        if cache_len < P:
+            raise ValueError(f"cache of {cache_len} slots for a prompt of {P}")
+        logits, _, kv = self.forward(params, {"tokens": tokens}, mode="prefill")
+        pad = ((0, 0), (0, 0), (0, 0), (0, cache_len - P), (0, 0))
+        dt = self.cfg.jnp_dtype
+        k, v = (jnp.pad(a.astype(dt), pad) for a in kv["kv"])
+        return logits, {"k": k, "v": v, "pos": jnp.asarray(P, jnp.int32)}
 
     def decode_step(self, params, token: jax.Array, cache: Dict[str, Any],
                     *, window: int = 0):

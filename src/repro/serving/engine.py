@@ -1,11 +1,13 @@
 """Batched serving engine with AMP4EC scheduling.
 
-Real greedy decoding (JAX, one decode_step per token) over model replicas
-"deployed" on simulated edge nodes; the AMP4EC TaskScheduler (NSA) routes
-each batch to a replica, and node time is charged via a FLOPs-based edge
-cost model, so the serving metrics (TTFT, per-token latency, throughput,
-load distribution) reflect the paper's scheduling behaviour while numerics
-stay real.
+Real greedy decoding (JAX) over model replicas "deployed" on simulated edge
+nodes: a dense decoder takes a group's prompt in one jitted prefill
+(``Model.prefill``), every other family steps ``decode_step`` through it one
+position at a time, and each new token is one ``decode_step``. The AMP4EC
+TaskScheduler (NSA) routes each batch to a replica, and node time is charged
+via a FLOPs-based edge cost model, so the serving metrics (TTFT, per-token
+latency, throughput, load distribution) reflect the paper's scheduling
+behaviour while numerics stay real.
 
 The batcher groups requests by prompt length (uniform-position batches match
 the scalar-position cache layout used by the production decode path).
@@ -41,7 +43,9 @@ def measured_ms(snapshot: dict) -> Dict[str, Optional[float]]:
     (``repro.utils.obs``): the mean time to a group's first token on the
     host (``amp4ec.prompt``), the mean gap between its generated tokens
     (``amp4ec.generate`` over new tokens less one) and the mean time to
-    route a group (``amp4ec.schedule``); None where nothing was recorded."""
+    route a group (``amp4ec.schedule``); and ``prefill_share``, the share
+    of prompt positions taken by a prefill rather than stepped. None where
+    nothing was recorded."""
     spans = snapshot["spans"]
 
     def mean(name):
@@ -52,7 +56,11 @@ def measured_ms(snapshot: dict) -> Dict[str, Optional[float]]:
     generate = [s for s in spans if s.name == "amp4ec.generate" and s.root_id in groups]
     gaps = sum(groups[s.root_id].attrs["new_tokens"] - 1 for s in generate)
     itl = sum(s.end - s.start for s in generate) / gaps * 1e3 if gaps > 0 else None
-    return dict(ttft_ms=mean("amp4ec.prompt"), itl_ms=itl, route_ms=mean("amp4ec.schedule"))
+    prompts = [s.attrs for s in spans if s.name == "amp4ec.prompt"]
+    prefilled = sum(a["prefilled"] for a in prompts)
+    positions = prefilled + sum(a["stepped"] for a in prompts)
+    return dict(ttft_ms=mean("amp4ec.prompt"), itl_ms=itl, route_ms=mean("amp4ec.schedule"),
+                prefill_share=prefilled / positions if positions else None)
 
 
 def cache_len(prompt_len: int, new_tokens: int) -> int:
@@ -84,6 +92,7 @@ class ServingEngine:
         self.scheduler = TaskScheduler()
         self.max_batch = max_batch
         self._decode_jit = jax.jit(self.model.decode_step)
+        self._prefill_jit = jax.jit(self.model.prefill, static_argnums=2)
         self._flops_per_token = 2.0 * self.model.param_count(params)
 
     # --- batching -------------------------------------------------------------
@@ -104,35 +113,46 @@ class ServingEngine:
         """Real greedy decode for a uniform-length group. Returns (B, N).
 
         ``amp4ec.prompt`` runs until the first generated token is on the
-        host, so the device backlog of the teacher-forced steps falls inside
-        it; ``amp4ec.generate`` runs from there until the last one is."""
-        cfg = self.cfg
+        host, so the device backlog of the prompt's work falls inside it;
+        it holds one ``amp4ec.prefill`` where the model ``can_prefill``,
+        else the teacher-forced steps. ``amp4ec.generate`` runs from there
+        until the last token is on the host."""
         B = len(group)
         P = len(group[0].prompt)
         N = group[0].max_new_tokens
-        cache, _ = self.model.init_cache(B, cache_len(P, N))
-        extras = {}
-        if cfg.family == "audio":
-            from repro.data.pipeline import frontend_stub
-            mem = jnp.asarray(frontend_stub("audio", B, cfg.num_frames, cfg.d_model))
-            cache = self.model.fill_cross_cache(self.params, cache, mem)
-        if cfg.family == "vlm":
-            from repro.data.pipeline import frontend_stub
-            mem = jnp.asarray(frontend_stub("vlm", B, cfg.num_image_tokens, cfg.d_model))
-            cache = self.model.fill_cross_cache(self.params, cache, mem)
-
         tokens = jnp.asarray(np.stack([r.prompt for r in group]), jnp.int32)
         out = []
-        with obs.span("amp4ec.prompt"):
-            for t in range(P - 1):               # teacher-forced prompt
-                _, cache = self._step(tokens[:, t], cache)
-            tok = tokens[:, P - 1]
-            if N:
-                tok, cache = self._next_token(tok, cache, out)
+        with obs.span("amp4ec.prompt") as prompt:
+            if self.model.can_prefill:
+                with obs.span("amp4ec.prefill"):
+                    logits, cache = self._prefill_jit(self.params, tokens, cache_len(P, N))
+                tok = self._sample(logits, out) if N else None
+                prompt.set(prefilled=B * P, stepped=0)
+            else:
+                tok, cache = self._teacher_force(tokens, N, out)
+                prompt.set(prefilled=0, stepped=B * (P - 1 + bool(N)))
         with obs.span("amp4ec.generate"):
             for _ in range(N - 1):
                 tok, cache = self._next_token(tok, cache, out)
         return np.stack(out, axis=1) if out else np.zeros((B, 0), np.int32)
+
+    def _teacher_force(self, tokens, N: int, out: list):
+        """The prompt one ``decode_step`` a position, and the first token;
+        returns it on the device and the cache."""
+        cfg = self.cfg
+        B, P = tokens.shape
+        cache, _ = self.model.init_cache(B, cache_len(P, N))
+        if cfg.family in ("audio", "vlm"):
+            from repro.data.pipeline import frontend_stub
+            frames = cfg.num_frames if cfg.family == "audio" else cfg.num_image_tokens
+            mem = jnp.asarray(frontend_stub(cfg.family, B, frames, cfg.d_model))
+            cache = self.model.fill_cross_cache(self.params, cache, mem)
+        for t in range(P - 1):
+            _, cache = self._step(tokens[:, t], cache)
+        tok = tokens[:, P - 1]
+        if N:
+            tok, cache = self._next_token(tok, cache, out)
+        return tok, cache
 
     def _step(self, tok, cache):
         with obs.span("amp4ec.step"):
@@ -143,10 +163,16 @@ class ServingEngine:
         ``out`` on the host; returns the token on the device and the
         cache."""
         logits, cache = self._step(tok, cache)
+        return self._sample(logits, out), cache
+
+    @staticmethod
+    def _sample(logits, out: list):
+        """The greedy token of ``logits``, appended to ``out`` on the host;
+        returns it on the device."""
         with obs.span("amp4ec.sample"):
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             out.append(np.asarray(tok))
-        return tok, cache
+        return tok
 
     # --- serving ------------------------------------------------------------------
 

@@ -24,7 +24,8 @@ ATTN_SHAPES = [
     (1, 2, 2, 128, 64),
     (2, 4, 2, 256, 64),    # GQA 2:1
     (1, 8, 1, 256, 128),   # MQA
-    (2, 2, 2, 384, 32),    # seq not a multiple of block
+    (2, 2, 2, 384, 32),    # three blocks
+    (1, 4, 2, 200, 64),    # seq not a multiple of the block: a ragged last block
 ]
 
 

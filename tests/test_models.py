@@ -209,3 +209,61 @@ def test_int8_kv_cache_decode_close_to_fp(rng):
     assert bool((jnp.argmax(l1, -1) == jnp.argmax(l2, -1)).all())
     tv = float(0.5 * jnp.abs(jax.nn.softmax(l1) - jax.nn.softmax(l2)).sum(-1).max())
     assert tv < 0.05, tv
+
+
+# the configurations whose full-sequence pass fills the decode cache
+PREFILL_ARCHS = ("chatglm3-6b", "qwen2.5-3b", "qwen2-7b", "yi-9b")
+
+
+@pytest.mark.parametrize("arch,prompt_len,impl", [
+    *((a, 16, None) for a in PREFILL_ARCHS),
+    ("qwen2.5-3b", 130, None),                 # a prompt not a multiple of 128
+    ("qwen2.5-3b", 130, "pallas_interpret"),   # the flash kernel's ragged tail
+])
+def test_prefill_then_decode_matches_stepped_decode(arch, prompt_len, impl, rng):
+    """``prefill`` and K decode steps give the logits and greedy tokens of
+    P - 1 teacher-forced steps and K + 1 more; its cache is the stepped
+    cache in slots [0, P), zeros after them, with ``pos`` = P."""
+    from repro.kernels import ops
+
+    cfg = dataclasses.replace(get_config(arch).reduced(), dtype="float32")
+    model = Model(cfg)
+    assert model.can_prefill
+    params, _ = model.init(rng)
+    B, P, K = 2, prompt_len, 3
+    cache_len = P + K + 1
+    tokens = jax.random.randint(jax.random.fold_in(rng, 7), (B, P), 0, cfg.vocab_size)
+    ops.set_default_impl(impl)
+    try:
+        logits, cache = jax.jit(model.prefill, static_argnums=2)(params, tokens, cache_len)
+    finally:
+        ops.set_default_impl(None)
+    step = jax.jit(model.decode_step)
+    ref, _ = model.init_cache(B, cache_len)
+    for t in range(P):
+        ref_logits, ref = step(params, tokens[:, t], ref)
+
+    assert int(cache["pos"]) == P and set(cache) == set(ref)
+    for name in ("k", "v"):
+        assert cache[name].shape == ref[name].shape and cache[name].dtype == ref[name].dtype
+        np.testing.assert_allclose(np.asarray(cache[name][..., :P, :]),
+                                   np.asarray(ref[name][..., :P, :]), rtol=1e-5, atol=1e-5)
+        assert not np.asarray(cache[name][..., P:, :]).any()
+    V = cfg.vocab_size
+    for _ in range(K + 1):
+        np.testing.assert_allclose(np.asarray(logits[:, :V]), np.asarray(ref_logits[:, :V]),
+                                   rtol=1e-4, atol=1e-4)
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        np.testing.assert_array_equal(np.asarray(tok), np.asarray(jnp.argmax(ref_logits, -1)))
+        logits, cache = step(params, tok, cache)
+        ref_logits, ref = step(params, tok, ref)
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_can_prefill_is_dense_with_a_plain_kv_cache(arch):
+    cfg = get_config(arch)
+    assert Model(cfg).can_prefill == (arch in PREFILL_ARCHS)
+    int8 = Model(dataclasses.replace(cfg, kv_cache_dtype="int8"))
+    assert not int8.can_prefill
+    with pytest.raises(ValueError):
+        int8.prefill(None, jnp.zeros((1, 4), jnp.int32), 8)

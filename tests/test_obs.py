@@ -135,7 +135,10 @@ def test_threads_keep_their_own_parents_and_lose_no_count():
 
 # --- what the program leaves behind ------------------------------------------
 
-def test_serve_leaves_prompt_generate_steps_and_samples(recording):
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "mamba2-130m"])
+def test_serve_leaves_prompt_generate_steps_and_samples(arch, recording):
+    """A dense decoder's prompt is one ``amp4ec.prefill``; another
+    family's is P - 1 teacher-forced steps and the first token's step."""
     import jax
     from repro.configs import get_config
     from repro.core.cluster import make_paper_cluster
@@ -143,9 +146,11 @@ def test_serve_leaves_prompt_generate_steps_and_samples(recording):
     from repro.serving import Request, ServingEngine
     from repro.serving.engine import measured_ms
 
-    cfg = get_config("qwen2.5-3b").reduced()
+    cfg = get_config(arch).reduced()
     params, _ = Model(cfg).init(jax.random.PRNGKey(0))
     engine = ServingEngine(cfg, params, make_paper_cluster(), max_batch=2)
+    prefills = engine.model.can_prefill
+    assert prefills == (arch == "qwen2.5-3b")
     P, N = 5, 4
     reqs = [Request(i, np.arange(1, P + 1, dtype=np.int32), N) for i in range(4)]
     obs.enable()                               # the set-up's spans go
@@ -159,23 +164,43 @@ def test_serve_leaves_prompt_generate_steps_and_samples(recording):
     for g in groups:
         mine = [s for s in snap["spans"] if s.root_id == g.span_id and s is not g]
         count = {n: sum(s.name == n for s in mine)
-                 for n in ("amp4ec.prompt", "amp4ec.generate", "amp4ec.step", "amp4ec.sample")}
+                 for n in ("amp4ec.prompt", "amp4ec.generate", "amp4ec.prefill",
+                           "amp4ec.step", "amp4ec.sample")}
         assert count == {"amp4ec.prompt": 1, "amp4ec.generate": 1,
-                         "amp4ec.step": P + N - 1, "amp4ec.sample": N}
+                         "amp4ec.prefill": int(prefills),
+                         "amp4ec.step": N - 1 if prefills else P + N - 1,
+                         "amp4ec.sample": N}
         prompt, = (s for s in mine if s.name == "amp4ec.prompt")
         generate, = (s for s in mine if s.name == "amp4ec.generate")
         assert g.start <= prompt.start < prompt.end <= generate.start < generate.end <= g.end
-        # the first token's step and sample end the prompt phase
+        assert prompt.attrs == (dict(prefilled=2 * P, stepped=0) if prefills
+                                else dict(prefilled=0, stepped=2 * P))
+        # the first token's sample ends the prompt phase
         assert sum(prompt.start <= s.start and s.end <= prompt.end
                    for s in mine if s.name == "amp4ec.sample") == 1
-        # P - 1 teacher-forced steps and the first token's step come before
-        # the first sample
+        # before the first sample: the prefill, or P - 1 teacher-forced
+        # steps and the first token's step
         first = min(s.start for s in mine if s.name == "amp4ec.sample")
-        assert sum(s.name == "amp4ec.step" and s.end <= first for s in mine) == P
+        before = [s.name for s in mine if s.end <= first and s.name != "amp4ec.prompt"]
+        assert before == (["amp4ec.prefill"] if prefills else ["amp4ec.step"] * P)
     t = measured_ms(snap)
     group_ms = sum(g.end - g.start for g in groups) / 2 * 1e3
     assert 0 < t["route_ms"] and 0 < t["itl_ms"]
     assert 0 < t["ttft_ms"] + (N - 1) * t["itl_ms"] <= group_ms
+    assert t["prefill_share"] == (1.0 if prefills else 0.0)
+
+
+def test_prefill_share_counts_positions_over_every_prompt():
+    from repro.serving.engine import measured_ms
+
+    def prompt(i, prefilled, stepped):
+        return obs.Span("amp4ec.prompt", 0.0, 1.0, i, None, i,
+                        dict(prefilled=prefilled, stepped=stepped))
+
+    assert measured_ms({"spans": []})["prefill_share"] is None
+    assert measured_ms({"spans": [prompt(1, 0, 0)]})["prefill_share"] is None
+    snap = {"spans": [prompt(1, 96, 0), prompt(2, 0, 32)]}
+    assert measured_ms(snap)["prefill_share"] == 0.75
 
 
 def test_infer_leaves_one_stage_per_partition_under_one_root(recording):
