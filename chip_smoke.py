@@ -59,7 +59,7 @@ from repro.models.model import Model  # noqa: E402
 from repro.models.rglru import lru_width  # noqa: E402
 from repro.models.ssm import ssm_dims  # noqa: E402
 from repro.serving import Request  # noqa: E402
-from repro.serving.engine import measured_ms  # noqa: E402
+from repro.serving.engine import measured_counts, measured_ms  # noqa: E402
 from repro.utils import obs  # noqa: E402
 
 # Every error below is max|out - reference| / max|reference|.
@@ -247,6 +247,7 @@ def phase_serving(cfg, *, requests: int = 4, prompt_len: int = 16,
     with _recording() as spans:
         again = serve()
     measured = measured_ms({"spans": spans})
+    counts = measured_counts({"spans": spans})
     _check(tokens.shape == (requests, new_tokens), f"tokens shape {tokens.shape}")
     _check(bool(((tokens >= 0) & (tokens < cfg.vocab_size)).all()),
            "token outside the vocabulary")
@@ -258,6 +259,9 @@ def phase_serving(cfg, *, requests: int = 4, prompt_len: int = 16,
     print("serving, measured on the host clock (ms): "
           + ", ".join(f"{k} {v:.3f}" for k, v in measured.items())
           + f"; prefill_share {share}")
+    if counts["routed_here"] is not None:
+        print("serving, MoE counters: " + ", ".join(f"{k} {v}" for k, v in counts.items()))
+        _check(counts["dropped"] == 0, "the serving path dropped routed tokens")
     print(f"serving tokens[0]: {tokens[0].tolist()}")
     return engine
 

@@ -53,8 +53,15 @@ class ModelConfig:
     top_k: int = 0
     d_ff_expert: int = 0
     first_dense_layers: int = 0      # deepseek-v2: layer 0 is a dense FFN
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # training only: serving drops no token
     router_aux_weight: float = 0.01
+    n_group: int = 1                 # group-limited routing (deepseek-v2): the
+    topk_group: int = 1              # topk_group of n_group expert groups with
+                                     # the highest top score, then top_k inside
+    norm_topk_prob: bool = True      # renormalise the top-k weights, else scale
+    routed_scaling_factor: float = 1.0   # them by this factor
+    experts_held: int = 0            # routed experts held here, from
+    first_expert_held: int = 0       # first_expert_held (0 = all num_experts)
 
     # --- MLA (deepseek-v2) ---
     use_mla: bool = False
@@ -63,6 +70,12 @@ class ModelConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    # YaRN rope (deepseek-v2's rope_scaling); factor 1.0 = plain rope
+    rope_factor: float = 1.0
+    rope_original_max_positions: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 0.0     # softmax scale x mscale(factor, this)^2
 
     # --- SSM (mamba2) ---
     ssm_state: int = 0
@@ -107,6 +120,10 @@ class ModelConfig:
         return ((self.vocab_size + 255) // 256) * 256
 
     @property
+    def num_experts_held(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
     def jnp_dtype(self):
         return jnp.dtype(self.dtype)
 
@@ -132,7 +149,10 @@ class ModelConfig:
                 d_ff_expert=128,
                 num_shared_experts=min(self.num_shared_experts, 1),
                 first_dense_layers=min(self.first_dense_layers, 1),
+                experts_held=0, first_expert_held=0,
             )
+            if self.n_group > 1:     # two groups of two, one kept
+                kw.update(n_group=2, topk_group=1)
         if self.use_mla:
             kw.update(kv_lora_rank=64, q_lora_rank=64, qk_nope_head_dim=32,
                       qk_rope_head_dim=16, v_head_dim=32)
@@ -157,6 +177,9 @@ class ModelConfig:
                     f"{self.name}: q heads {self.num_heads} not divisible by kv {self.num_kv_heads}"
         if self.num_experts:
             assert 0 < self.top_k <= self.num_experts
+            assert self.num_experts % self.n_group == 0
+            assert self.top_k <= self.topk_group * self.num_experts // self.n_group
+            assert self.first_expert_held + self.num_experts_held <= self.num_experts
         if self.block_pattern:
             assert set(self.block_pattern) <= {"rec", "attn"}
 

@@ -1,4 +1,9 @@
-"""deepseek-v2-236b [moe] — MLA kv_lora=512, 2 shared + 160 routed top-6 [arXiv:2405.04434]."""
+"""deepseek-v2-236b [moe] — MLA kv_lora=512, 2 shared + 160 routed top-6 [arXiv:2405.04434].
+
+Routing, rope and norm as in the model's config.json: group-limited greedy
+top-6 (3 of 8 groups), weights not renormalised but scaled by 16, YaRN rope
+(factor 40 over 4096 positions), RMSNorm eps 1e-6. No sliding window.
+"""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
@@ -10,6 +15,9 @@ CONFIG = ModelConfig(
     first_dense_layers=1,
     use_mla=True, kv_lora_rank=512, q_lora_rank=1536,
     qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
-    rope_theta=10000.0, act="silu", norm="rmsnorm",
-    long_context="sliding",
+    n_group=8, topk_group=3, norm_topk_prob=False, routed_scaling_factor=16.0,
+    rope_theta=10000.0, act="silu", norm="rmsnorm", norm_eps=1e-6,
+    rope_factor=40.0, rope_original_max_positions=4096, rope_beta_fast=32.0,
+    rope_beta_slow=1.0, rope_mscale_all_dim=0.707,
+    long_context="skip",
 )

@@ -22,7 +22,7 @@ from repro.core.cluster import make_paper_cluster
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.serving import Request, ServingEngine
-from repro.serving.engine import SIMULATED, measured_ms
+from repro.serving.engine import SIMULATED, measured_counts, measured_ms
 from repro.utils import obs
 
 
@@ -63,6 +63,11 @@ def main():
     for k, v in measured.items():
         print(f"{k} (measured on the host clock, compiles included): {v}")
     print(f"prefill_share (prompt positions taken by one prefill): {share}")
+    counts = measured_counts(obs.snapshot())
+    if counts["routed_here"] is not None:
+        print("MoE counters (assignments computed by the held experts, most on one "
+              "expert in one layer, dropped): "
+              + ", ".join(f"{k} {v}" for k, v in counts.items()))
 
 
 if __name__ == "__main__":

@@ -50,11 +50,13 @@ def rope_freqs(dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float, pct: float = 1.0) -> jax.Array:
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float, pct: float = 1.0,
+               freqs=None) -> jax.Array:
     """x: (..., S, H, D) or (..., S, D); positions: (..., S) or (S,).
 
     ``pct`` < 1 applies rotary to the leading ``pct * D`` dims only
-    (ChatGLM's 2d/partial rotary).
+    (ChatGLM's 2d/partial rotary). ``freqs`` (d_rot/2,) replaces the plain
+    inverse frequencies of ``theta`` (YaRN's, in MLA).
     """
     if theta <= 0:
         return x
@@ -62,7 +64,7 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float, pct: float = 1.
     d_rot = int(d * pct)
     d_rot -= d_rot % 2
     xr, xp = x[..., :d_rot], x[..., d_rot:]
-    freqs = rope_freqs(d_rot, theta)                       # (d_rot/2,)
+    freqs = rope_freqs(d_rot, theta) if freqs is None else jnp.asarray(freqs, jnp.float32)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., S, d_rot/2)
     if x.ndim == 4:  # (..., S, H, D): insert the head axis for broadcasting
         ang = jnp.expand_dims(ang, -2)

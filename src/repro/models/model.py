@@ -36,6 +36,10 @@ from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 
 Pytree = Any
 
+# the most that the expanded per-head q, k and v of one block of a prefill
+# may take (``Model.prefill_rows``)
+PREFILL_BLOCK_BYTES = 1 << 30
+
 
 # ---------------------------------------------------------------------------
 # per-family block init
@@ -235,6 +239,8 @@ class Model:
     # -- block applications (full sequence) ---------------------------------
 
     def _dense_block(self, p, x, positions, *, window, use_moe, collect_kv=False):
+        """One decoder block. Returns (x, aux, kv or None, MoE counts or
+        None); with ``collect_kv`` (a prefill) the experts drop nothing."""
         cfg = self.cfg
         h = L.apply_norm(p["ln1"], x, cfg.norm, cfg.norm_eps)
         if cfg.use_mla:
@@ -247,14 +253,18 @@ class Model:
         attn_out = shard(attn_out, "batch", "seq", None)
         x = x + _checkpoint_name(attn_out, "blk_out")
         h = L.apply_norm(p["ln2"], x, cfg.norm, cfg.norm_eps)
-        if use_moe:
+        counts = None
+        if use_moe and collect_kv:
+            ffn_out, counts = MOE.serve_moe(p["ffn"], h, cfg, impl=self.moe_impl)
+            aux = jnp.zeros((1,), jnp.float32)
+        elif use_moe:
             ffn_out, aux = MOE.apply_moe(p["ffn"], h, cfg, impl=self.moe_impl)
         else:
             ffn_out, aux = L.apply_mlp(p["ffn"], h, cfg), jnp.zeros((1,), jnp.float32)
         ffn_out = shard(ffn_out, "batch", "seq", None)
         x = x + _checkpoint_name(ffn_out, "blk_out")
         x = shard(x, "batch", "seq", None)
-        return x, aux, (kv if collect_kv else None)
+        return x, aux, (kv if collect_kv else None), counts
 
     def _window(self, shape_kind: str) -> int:
         """Attention window for a given execution (0 = full)."""
@@ -273,7 +283,9 @@ class Model:
 
         batch: {"tokens": (B, S) int32 [, "frames": (B, F, D), "images": (B, I, D)]}
         Returns (logits (B, S, V), aux_loss scalar, cache_or_None).
-        mode: "train" (logits over all positions) or "prefill" (also returns cache).
+        mode: "train" (logits over all positions) or "prefill" (also returns
+        cache, and MoE layers drop nothing and give their counts as
+        ``cache["moe_counts"]`` (layers, 3)).
         """
         cfg = self.cfg
         tokens = batch["tokens"]
@@ -310,10 +322,10 @@ class Model:
                             x = SMB.dense_block_shardmap(
                                 p, x, cfg, rules.mesh, window=window)
                             return (x, aux), None
-                    x, a, kv = self._dense_block(
+                    x, a, kv, counts = self._dense_block(
                         p, x, positions, window=window, use_moe=use_moe,
                         collect_kv=collect)
-                    return (x, aux + a.mean()), kv
+                    return (x, aux + a.mean()), (kv if counts is None else (kv, counts))
                 return body
 
             if fd:
@@ -323,7 +335,9 @@ class Model:
                     caches["dense_kv"] = kv_d
             (x, aux_total), kv_m = self._scan(
                 maybe_remat(mk_body(cfg.family == "moe")), (x, aux_total), params["blocks"])
-            if collect:
+            if collect and cfg.family == "moe":
+                caches["kv"], caches["moe_counts"] = kv_m
+            elif collect:
                 caches["kv"] = kv_m
 
         elif cfg.family == "ssm":
@@ -407,7 +421,7 @@ class Model:
                 x, aux = carry
                 kvs = {}
                 for j in range(n_self):
-                    x, a, kv = self._dense_block(
+                    x, a, kv, _ = self._dense_block(
                         p[f"self{j}"], x, positions, window=window,
                         use_moe=False, collect_kv=collect)
                     aux = aux + a.mean()
@@ -539,6 +553,9 @@ class Model:
                             scs, jnp.float32)
                         put("d_v_scale", (fd, batch, cfg.num_kv_heads, cache_len),
                             scs, jnp.float32)
+            if cfg.family == "moe":
+                # (assignments computed here, most on one expert, dropped)
+                put("moe_counts", (3,), (None,), jnp.int32)
         elif cfg.family == "ssm":
             di, H, G, d_bc = SSM.ssm_dims(cfg)
             nconv = di + 2 * d_bc
@@ -597,32 +614,84 @@ class Model:
     @property
     def can_prefill(self) -> bool:
         """Whether ``prefill`` fills the cache that stepping ``decode_step``
-        through the prompt would: a dense decoder with a plain k/v cache.
-        Elsewhere the full-sequence pass computes something else (moe
-        experts drop tokens at capacity, single steps never do), or the
-        cache has another layout (MLA, int8) or holds recurrent or cross
-        state."""
+        through the prompt would: a dense or MoE decoder whose cache is a
+        plain k/v or MLA latent cache. MoE layers drop no token on either
+        path, so the full-sequence pass routes each token as a step does.
+        Elsewhere the cache has another layout (int8) or holds recurrent or
+        cross state."""
         cfg = self.cfg
-        return cfg.family == "dense" and not cfg.use_mla and cfg.kv_cache_dtype != "int8"
+        return cfg.family in ("dense", "moe") and cfg.kv_cache_dtype != "int8"
+
+    def prefill_rows(self, batch: int, prompt_len: int) -> int:
+        """Sequences per block of the prefill: the most, dividing ``batch``,
+        whose per-head q, k and v take at most ``PREFILL_BLOCK_BYTES``
+        (MLA expands its latent to 128 heads of 192 + 192 + 128)."""
+        cfg = self.cfg
+        if cfg.use_mla:
+            width = cfg.num_heads * (2 * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+                                     + cfg.v_head_dim)
+        else:
+            width = (cfg.num_heads + 2 * cfg.num_kv_heads) * cfg.head_dim_
+        per_row = prompt_len * width * cfg.jnp_dtype.itemsize
+        rows = batch
+        while rows > 1 and (batch % rows or rows * per_row > PREFILL_BLOCK_BYTES):
+            rows -= 1
+        return rows
 
     def prefill(self, params, tokens: jax.Array, cache_len: int):
-        """The whole prompt in one full-sequence pass. tokens: (B, P) int32.
+        """The whole prompt in full-sequence passes. tokens: (B, P) int32.
 
         Returns (logits of the last position (B, V), the decode cache of
         ``cache_len`` slots that ``init_cache`` describes, with the prompt's
-        k/v in slots [0, P), zeros after them and ``pos`` = P). Only where
-        ``can_prefill``."""
+        k/v or latents in slots [0, P), zeros after them, ``pos`` = P and,
+        for MoE, the prompt's counts). Blocks of ``prefill_rows`` sequences
+        go through one after another inside the call, each writing its rows
+        of the cache. Only where ``can_prefill``."""
         if not self.can_prefill:
-            raise ValueError(f"{self.cfg.name}: prefill needs a dense decoder "
-                             "with a plain k/v cache")
-        P = tokens.shape[1]
+            raise ValueError(f"{self.cfg.name}: prefill needs a dense or MoE decoder "
+                             "with a plain k/v or latent cache")
+        B, P = tokens.shape
         if cache_len < P:
             raise ValueError(f"cache of {cache_len} slots for a prompt of {P}")
+        rows = self.prefill_rows(B, P)
+        if rows == B:
+            return self._prefill_block(params, tokens, cache_len)
+
+        def block(i, carry):
+            logits, cache = carry
+            lg, part = self._prefill_block(
+                params, jax.lax.dynamic_slice_in_dim(tokens, i * rows, rows), cache_len)
+            logits = jax.lax.dynamic_update_slice_in_dim(logits, lg, i * rows, 0)
+            cache = {name: (MOE.add_counts(a, part[name][None]) if name == "moe_counts"
+                            else a if name == "pos"
+                            else jax.lax.dynamic_update_slice_in_dim(a, part[name],
+                                                                     i * rows, 1))
+                     for name, a in cache.items()}
+            return logits, cache
+
+        cache, _ = self.init_cache(B, cache_len)
+        cache["pos"] = jnp.asarray(P, jnp.int32)
+        logits = jnp.zeros((B, self.cfg.padded_vocab), self.cfg.jnp_dtype)
+        return jax.lax.fori_loop(0, B // rows, block, (logits, cache))
+
+    def _prefill_block(self, params, tokens: jax.Array, cache_len: int):
+        """``prefill`` of one block of sequences in one pass."""
+        cfg = self.cfg
+        P = tokens.shape[1]
         logits, _, kv = self.forward(params, {"tokens": tokens}, mode="prefill")
-        pad = ((0, 0), (0, 0), (0, 0), (0, cache_len - P), (0, 0))
-        dt = self.cfg.jnp_dtype
-        k, v = (jnp.pad(a.astype(dt), pad) for a in kv["kv"])
-        return logits, {"k": k, "v": v, "pos": jnp.asarray(P, jnp.int32)}
+        dt = cfg.jnp_dtype
+        if cfg.use_mla:       # (layers, B, P, width)
+            names, pad = ("ckv", "krope"), ((0, 0), (0, 0), (0, cache_len - P), (0, 0))
+        else:                 # (layers, B, heads, P, head_dim)
+            names, pad = ("k", "v"), ((0, 0), (0, 0), (0, 0), (0, cache_len - P), (0, 0))
+        cache = dict(zip(names, (jnp.pad(a.astype(dt), pad) for a in kv["kv"])))
+        if "dense_kv" in kv:
+            cache.update(zip(("d_" + n for n in names),
+                             (jnp.pad(a.astype(dt), pad) for a in kv["dense_kv"])))
+        if "moe_counts" in kv:
+            cache["moe_counts"] = MOE.add_counts(jnp.zeros((3,), jnp.int32), kv["moe_counts"])
+        cache["pos"] = jnp.asarray(P, jnp.int32)
+        return logits, cache
 
     def decode_step(self, params, token: jax.Array, cache: Dict[str, Any],
                     *, window: int = 0):
@@ -659,10 +728,9 @@ class Model:
                     x = x + out
                     h = L.apply_norm(p["ln2"], x, cfg.norm, cfg.norm_eps)
                     if use_moe:
-                        f, _ = MOE.apply_moe(p["ffn"], h, cfg, impl=self.moe_impl)
-                    else:
-                        f = L.apply_mlp(p["ffn"], h, cfg)
-                    return x + f, nc
+                        f, counts = MOE.serve_moe(p["ffn"], h, cfg, impl=self.moe_impl)
+                        return x + f, (nc, counts)
+                    return x + L.apply_mlp(p["ffn"], h, cfg), nc
                 return body
 
             if cfg.use_mla:
@@ -681,6 +749,9 @@ class Model:
             x, outs = self._scan(
                 mk_body(cfg.family == "moe"), x,
                 (params["blocks"], tuple(cache[n] for n in kv_names)))
+            if cfg.family == "moe":
+                outs, counts = outs
+                new_cache["moe_counts"] = MOE.add_counts(cache["moe_counts"], counts)
             for nm, arr in zip(kv_names, outs):
                 new_cache[nm] = arr
 

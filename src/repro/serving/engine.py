@@ -1,7 +1,7 @@
 """Batched serving engine with AMP4EC scheduling.
 
 Real greedy decoding (JAX) over model replicas "deployed" on simulated edge
-nodes: a dense decoder takes a group's prompt in one jitted prefill
+nodes: a dense or MoE decoder takes a group's prompt in one jitted prefill
 (``Model.prefill``), every other family steps ``decode_step`` through it one
 position at a time, and each new token is one ``decode_step``. The AMP4EC
 TaskScheduler (NSA) routes each batch to a replica, and node time is charged
@@ -63,6 +63,25 @@ def measured_ms(snapshot: dict) -> Dict[str, Optional[float]]:
                 prefill_share=prefilled / positions if positions else None)
 
 
+COUNTS = ("routed_here", "expert_load_max", "dropped")
+
+
+def measured_counts(snapshot: dict) -> Dict[str, Optional[int]]:
+    """The MoE counters of ``serve`` calls, from a snapshot of the recorder:
+    over the ``amp4ec.prompt`` and ``amp4ec.generate`` spans, the routed
+    assignments the held experts computed (``routed_here``), the most that
+    one held expert took in one layer of one pass (``expert_load_max``) and
+    the assignments dropped (``dropped``). None where no span carries them
+    (no MoE layer)."""
+    found = [s.attrs for s in snapshot["spans"]
+             if s.name in ("amp4ec.prompt", "amp4ec.generate") and COUNTS[0] in s.attrs]
+    if not found:
+        return dict.fromkeys(COUNTS)
+    return dict(routed_here=sum(a["routed_here"] for a in found),
+                expert_load_max=max(a["expert_load_max"] for a in found),
+                dropped=sum(a["dropped"] for a in found))
+
+
 def cache_len(prompt_len: int, new_tokens: int) -> int:
     """Cache slots a group decodes into: every position, and one spare."""
     return prompt_len + new_tokens + 1
@@ -95,6 +114,11 @@ class ServingEngine:
         self._prefill_jit = jax.jit(self.model.prefill, static_argnums=2)
         self._flops_per_token = 2.0 * self.model.param_count(params)
 
+    def _cache_bytes(self, batch: int, slots: int) -> int:
+        """Device bytes of the decode cache of a group."""
+        cache, _ = self.model.init_cache(batch, slots, abstract=True)
+        return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+
     # --- batching -------------------------------------------------------------
 
     def _buckets(self, requests: List[Request]) -> List[List[Request]]:
@@ -116,7 +140,8 @@ class ServingEngine:
         host, so the device backlog of the prompt's work falls inside it;
         it holds one ``amp4ec.prefill`` where the model ``can_prefill``,
         else the teacher-forced steps. ``amp4ec.generate`` runs from there
-        until the last token is on the host."""
+        until the last token is on the host. With the recorder on, each of
+        the two carries the MoE counters of its passes (``COUNTS``)."""
         B = len(group)
         P = len(group[0].prompt)
         N = group[0].max_new_tokens
@@ -131,10 +156,22 @@ class ServingEngine:
             else:
                 tok, cache = self._teacher_force(tokens, N, out)
                 prompt.set(prefilled=0, stepped=B * (P - 1 + bool(N)))
-        with obs.span("amp4ec.generate"):
+            cache = self._take_counts(prompt, cache)
+        with obs.span("amp4ec.generate") as generate:
             for _ in range(N - 1):
                 tok, cache = self._next_token(tok, cache, out)
+            self._take_counts(generate, cache)
         return np.stack(out, axis=1) if out else np.zeros((B, 0), np.int32)
+
+    @staticmethod
+    def _take_counts(span, cache):
+        """With the recorder on, put the MoE counts that ``cache`` carries on
+        ``span`` and return the cache with them zeroed; else return it as it
+        is, with nothing read from the device."""
+        if not obs.enabled() or "moe_counts" not in cache:
+            return cache
+        span.set(**dict(zip(COUNTS, (int(c) for c in np.asarray(cache["moe_counts"])))))
+        return dict(cache, moe_counts=jnp.zeros((3,), jnp.int32))
 
     def _teacher_force(self, tokens, N: int, out: list):
         """The prompt one ``decode_step`` a position, and the first token;
@@ -206,6 +243,7 @@ class ServingEngine:
                                       key=lambda n: n.busy_until_ms).node_id
                 attrs = (dict(batch=len(group), prompt_len=P, new_tokens=N,
                               cache_len=cache_len(P, N), node=node_id,
+                              cache_bytes=self._cache_bytes(len(group), cache_len(P, N)),
                               requests=[r.request_id for r in group])
                          if obs.enabled() else {})
                 with obs.root("amp4ec.group", **attrs):

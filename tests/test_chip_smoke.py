@@ -58,3 +58,12 @@ def test_main_refuses_without_tpu(capsys):
         chip_smoke.main()
     assert exc.value.code not in (0, None)
     assert '"ok"' not in capsys.readouterr().out
+
+
+def test_serving_phase_prints_the_moe_counters(capsys):
+    engine = chip_smoke.phase_serving(f32_reduced("deepseek-v2-236b"), requests=2,
+                                      prompt_len=4, new_tokens=3)
+    assert engine.model.can_prefill
+    lines = [ln for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("serving, MoE counters")]
+    assert len(lines) == 1 and "dropped 0" in lines[0] and "routed_here" in lines[0]

@@ -11,30 +11,31 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd_scan
 
 
-def _qkv(key, b, hq, hkv, s, d, dtype):
+def _qkv(key, b, hq, hkv, s, d, dtype, dv=None):
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (b, hq, s, d), jnp.float32).astype(dtype)
     k = jax.random.normal(ks[1], (b, hkv, s, d), jnp.float32).astype(dtype)
-    v = jax.random.normal(ks[2], (b, hkv, s, d), jnp.float32).astype(dtype)
+    v = jax.random.normal(ks[2], (b, hkv, s, dv or d), jnp.float32).astype(dtype)
     return q, k, v
 
 
 ATTN_SHAPES = [
-    # (batch, q heads, kv heads, seq, head dim)
+    # (batch, q heads, kv heads, seq, head dim[, value dim])
     (1, 2, 2, 128, 64),
     (2, 4, 2, 256, 64),    # GQA 2:1
     (1, 8, 1, 256, 128),   # MQA
     (2, 2, 2, 384, 32),    # three blocks
     (1, 4, 2, 200, 64),    # seq not a multiple of the block: a ragged last block
+    (1, 2, 2, 256, 192, 128),   # MLA (DeepSeek-V2): q.k over 128 + 64, v 128
 ]
 
 
 @pytest.mark.parametrize("shape", ATTN_SHAPES)
 @pytest.mark.parametrize("causal,window", [(True, 0), (True, 64), (False, 0)])
 def test_flash_attention_vs_ref(shape, causal, window):
-    b, hq, hkv, s, d = shape
+    b, hq, hkv, s, d, *dv = shape
     q, k, v = _qkv(jax.random.PRNGKey(hash((shape, causal, window)) % 2**31),
-                   b, hq, hkv, s, d, jnp.float32)
+                   b, hq, hkv, s, d, jnp.float32, *dv)
     out_ref = ref.attention_ref(q, k, v, causal=causal, window=window)
     out = flash_attention(q, k, v, causal=causal, window=window, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),

@@ -211,8 +211,10 @@ def test_int8_kv_cache_decode_close_to_fp(rng):
     assert tv < 0.05, tv
 
 
-# the configurations whose full-sequence pass fills the decode cache
-PREFILL_ARCHS = ("chatglm3-6b", "qwen2.5-3b", "qwen2-7b", "yi-9b")
+# the configurations whose full-sequence pass fills the decode cache: dense
+# and MoE decoders with a plain k/v or MLA latent cache
+PREFILL_ARCHS = ("chatglm3-6b", "deepseek-v2-236b", "kimi-k2-1t-a32b", "qwen2.5-3b",
+                 "qwen2-7b", "yi-9b")
 
 
 @pytest.mark.parametrize("arch,prompt_len,impl", [
@@ -244,7 +246,7 @@ def test_prefill_then_decode_matches_stepped_decode(arch, prompt_len, impl, rng)
         ref_logits, ref = step(params, tokens[:, t], ref)
 
     assert int(cache["pos"]) == P and set(cache) == set(ref)
-    for name in ("k", "v"):
+    for name in set(cache) - {"pos", "moe_counts"}:
         assert cache[name].shape == ref[name].shape and cache[name].dtype == ref[name].dtype
         np.testing.assert_allclose(np.asarray(cache[name][..., :P, :]),
                                    np.asarray(ref[name][..., :P, :]), rtol=1e-5, atol=1e-5)
@@ -267,3 +269,14 @@ def test_can_prefill_is_dense_with_a_plain_kv_cache(arch):
     assert not int8.can_prefill
     with pytest.raises(ValueError):
         int8.prefill(None, jnp.zeros((1, 4), jnp.int32), 8)
+
+
+def test_prefill_blocks_follow_the_expanded_attention():
+    """The qwen2.5-3b cells' prompts (32 x 128, 32 x 1024) go through in one
+    block; DeepSeek-V2's 128 x 1024, whose q, k and v expand to 128 heads
+    of 192 + 192 + 128 (134 MB a sequence), in blocks of 8 sequences."""
+    qwen = Model(get_config("qwen2.5-3b"))
+    assert qwen.prefill_rows(32, 128) == qwen.prefill_rows(32, 1024) == 32
+    deepseek = Model(get_config("deepseek-v2-236b"))
+    assert deepseek.prefill_rows(128, 1024) == 8
+    assert deepseek.prefill_rows(12, 1024) == 6           # the most that divides the batch

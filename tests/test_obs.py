@@ -159,8 +159,10 @@ def test_serve_leaves_prompt_generate_steps_and_samples(arch, recording):
     groups = _named(snap, "amp4ec.group")
     assert len(groups) == 2 and len(_named(snap, "amp4ec.serve")) == 1
     assert len(_named(snap, "amp4ec.schedule")) == 2
+    cache, _ = engine.model.init_cache(2, P + N + 1)
     assert groups[0].attrs == dict(batch=2, prompt_len=P, new_tokens=N, cache_len=P + N + 1,
-                                   node=reqs[0].node_id, requests=[0, 1])
+                                   node=reqs[0].node_id, requests=[0, 1],
+                                   cache_bytes=sum(a.nbytes for a in jax.tree.leaves(cache)))
     for g in groups:
         mine = [s for s in snap["spans"] if s.root_id == g.span_id and s is not g]
         count = {n: sum(s.name == n for s in mine)
@@ -188,6 +190,59 @@ def test_serve_leaves_prompt_generate_steps_and_samples(arch, recording):
     assert 0 < t["route_ms"] and 0 < t["itl_ms"]
     assert 0 < t["ttft_ms"] + (N - 1) * t["itl_ms"] <= group_ms
     assert t["prefill_share"] == (1.0 if prefills else 0.0)
+
+
+def test_moe_counters_count_what_the_reference_routes_to_the_held_experts(recording):
+    """DeepSeek-V2's block at tiny widths (4 of 8 routed experts held):
+    ``routed_here`` on ``amp4ec.prompt`` and ``amp4ec.generate`` is the
+    number of assignments the reference routes to the held experts at the
+    positions each phase passes through, and ``dropped`` is 0; with the
+    recorder off, the spans carry nothing."""
+    from pathlib import Path
+    import jax
+    import jax.numpy as jnp
+    sys.path[:0] = [str(Path(__file__).resolve().parents[1])]
+    from bench import generate, harness
+    from bench.paths import serving_moe
+    from bench.ref import deepseek_v2 as ref
+    from repro.core.cluster import make_paper_cluster
+    from repro.models.model import Model
+    from repro.serving import Request, ServingEngine
+    from repro.serving.engine import measured_counts
+
+    config = dict(harness.data("configs", "deepseek-v2-ep8"), hidden_size=64,
+                  intermediate_size=96, num_hidden_layers=3, num_attention_heads=4,
+                  num_key_value_heads=4, q_lora_rank=32, kv_lora_rank=16,
+                  qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+                  moe_intermediate_size=16, n_group=4, topk_group=2, num_experts_per_tok=3,
+                  n_routed_experts=4, first_routed_expert=2, vocab_size=500,
+                  torch_dtype="float32",
+                  published=dict(num_hidden_layers=60, n_routed_experts=8))
+    cfg = serving_moe.model_config(config)
+    abstract, _ = Model(cfg).init(abstract=True)
+    params = serving_moe.make_weights(abstract, jnp.asarray(generate.key_words(8, 3)))
+    engine = ServingEngine(cfg, params, make_paper_cluster(), max_batch=2)
+    P, N = 9, 4
+    prompts = generate.rng(9, 2).integers(0, 500, (2, P)).astype(np.int32)
+    reqs = [Request(i, prompts[i], N) for i in range(2)]
+    engine.serve(reqs)
+    snap = obs.snapshot()
+    prompt, = _named(snap, "amp4ec.prompt")
+    gen, = _named(snap, "amp4ec.generate")
+    tokens = np.concatenate([prompts, np.stack([r.output for r in reqs])[:, :-1]], 1)
+    with jax.default_matmul_precision("highest"):
+        chosen = np.asarray(ref.routes(config, params, tokens, 0, P + N - 1))
+    held = (chosen >= 2) & (chosen < 6)                  # (layers, n, positions, k)
+    assert prompt.attrs["routed_here"] == held[:, :, :P].sum() > 0
+    assert gen.attrs["routed_here"] == held[:, :, P:].sum() > 0
+    assert prompt.attrs["dropped"] == gen.attrs["dropped"] == 0
+    assert 0 < gen.attrs["expert_load_max"] <= 2 < prompt.attrs["expert_load_max"]
+    counts = measured_counts(snap)
+    assert counts == dict(routed_here=held.sum(), dropped=0,
+                          expert_load_max=prompt.attrs["expert_load_max"])
+    obs.disable()
+    engine.serve([Request(9, prompts[0], N)])
+    assert measured_counts(obs.snapshot()) == counts          # nothing new recorded
 
 
 def test_prefill_share_counts_positions_over_every_prompt():
