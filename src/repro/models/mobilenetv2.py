@@ -2,9 +2,9 @@
 
 Structured as an explicit *leaf-layer list* (the same 141 leaves the graph in
 ``models.graph.mobilenetv2_graph`` describes) so AMP4EC partitions — which
-are contiguous leaf ranges — can be executed layer-by-layer on different
-simulated edge nodes, and partitioned output can be asserted identical to the
-monolithic forward.
+are contiguous leaf ranges — can be executed on different simulated edge
+nodes, each as one compiled program over its leaves (``run_range``), and
+partitioned output can be asserted identical to the monolithic forward.
 
 Residual adds are attached to the *last* leaf of each inverted-residual
 block (the projection BN), mirroring how layer-wise partial inference treats
@@ -14,6 +14,7 @@ between partitions.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -22,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import mobilenetv2 as C
+from repro.utils import obs
 
 
 @dataclass
@@ -123,17 +125,47 @@ def build_mobilenetv2(rng: Optional[jax.Array] = None) -> List[Leaf]:
     return leaves
 
 
+_traced = 0                          # stage programs traced in this process
+
+
+@functools.lru_cache(maxsize=256)
+def _stage_program(steps: Tuple[Tuple[Callable, bool, bool], ...]) -> Callable:
+    """One jitted program per stage, keyed by the stage's leaf functions and
+    residual flags (the callables themselves: a leaf rebuilt with another
+    ``apply`` gets another program). Weights are arguments, never constants,
+    so leaves rebuilt with other ``params`` reuse the program; shapes,
+    dtypes, devices and the matmul precision are ``jax.jit``'s own key."""
+    def stage(params, x, residual):
+        global _traced
+        _traced += 1
+        for (apply, save_residual, add_residual), p in zip(steps, params):
+            if save_residual:
+                residual = x
+            x, residual = apply(p, x, residual)
+            if add_residual:
+                x = x + residual
+                residual = None
+        return x, residual
+
+    return jax.jit(stage)
+
+
 def run_range(leaves: List[Leaf], lo: int, hi: int, x: jax.Array,
               residual: Optional[jax.Array] = None):
-    """Execute leaves [lo, hi) — one AMP4EC partition. Returns (x, residual)."""
-    for leaf in leaves[lo:hi]:
-        if leaf.save_residual:
-            residual = x
-        x, residual = leaf.apply(leaf.params, x, residual)
-        if leaf.add_residual:
-            x = x + residual
-            residual = None
-    return x, residual
+    """Execute leaves [lo, hi) — one AMP4EC partition — as one compiled
+    program. Returns (x, residual).
+
+    While ``obs`` records, an ``amp4ec.stage_program`` span (``lo``, ``hi``)
+    carries ``built``: the programs traced during the call (0 on a reused
+    one, 1 on a new stage or a new shape)."""
+    stage = leaves[lo:hi]
+    program = _stage_program(tuple((leaf.apply, leaf.save_residual, leaf.add_residual)
+                                   for leaf in stage))
+    with obs.span("amp4ec.stage_program", lo=lo, hi=hi) as sp:
+        before = _traced
+        out = program([leaf.params for leaf in stage], x, residual)
+        sp.set(built=_traced - before)
+    return out
 
 
 def run_full(leaves: List[Leaf], x: jax.Array) -> jax.Array:
