@@ -39,14 +39,6 @@ The sections, written to ``BENCH_pipeline.json`` (repo root):
     simulated goodput (asserted in-bench, pinned exactly). A final row
     prints the committed kernel-calibration artifact's predicted
     testbed bottleneck next to the analytic model's.
-``eventspersec``
-    The fast-event-core headline: 16 placement-disjoint tenants × 50
-    nodes through (a) the heap oracle, (b) the time-wheel core with
-    sharding off — asserted **bit-for-bit equal** to the oracle, same
-    dispatched event count — and (c) the time-wheel core with automatic
-    tenant sharding. Reports events-per-wall-second per row and asserts
-    the sharded core's ≥10× events/sec speedup over the heap oracle
-    in-bench (the ISSUE-7 acceptance bar).
 ``dagsweep``
     Operator-DAG dataflow: an MoE-style branched plan (trunk → two
     asymmetric expert arms → join → tail) with a trunk early-exit head
@@ -492,240 +484,6 @@ def faultstorm_rows(num_requests: int = FS_REQUESTS):
     return rows
 
 
-# --- fast event core ---------------------------------------------------------
-
-#: the events/sec scenario: placement-disjoint tenants on 3-node slices of
-#: the 50-node cluster, lightly loaded (2000 ms arrival gap > the ~1.5 s
-#: per-request chain), so the uncontended fused path and tenant sharding
-#: both engage — the operating point the fast core is built for
-EV_TENANTS = 16
-EV_NODES = 50
-EV_REQUESTS = 10_000         # total, split across the tenants
-EV_RATE_RPS = 0.5            # per tenant
-EV_CONCURRENCY = 8
-#: the fast core's acceptance bar: sharded events/sec vs the heap oracle
-EV_SPEEDUP_FLOOR = 10.0
-
-#: the contended events/sec variant: the same 16 disjoint 3-node slices,
-#: but driven hot (Poisson arrivals, concurrency 32, adaptive micro-batch
-#: 8), so back-to-back same-node micro-batches dominate the stream — the
-#: operating point contended-chain fusion plus forked sharding targets
-EVC_RATE_RPS = 8.0
-EVC_CONCURRENCY = 32
-EVC_WORKERS = 4
-#: the adaptive events/sec variant: every tenant carries an
-#: AdaptationController scoped to its own disjoint ``nodes=`` closure, so
-#: the sharder free-runs the groups between 1 Hz epoch barriers and the
-#: coordinator polls each closure locally instead of the whole fleet.
-#: 32 tenants: the interleaved tick cost scales with streams × fleet
-#: size, the closure tick with streams × closure size, so the fleet is
-#: sized where that gap (not noise) dominates the measured ratio
-EVA_TENANTS = 32
-EVA_NODES_PER = 3
-EVA_REQUESTS = 9_600
-EVA_RATE_RPS = 12.0
-EVA_CONCURRENCY = 24
-#: both sharded variants must clear this × the *interleaved* fast core
-EV_SHARD_FLOOR = 2.0
-#: the forked lane re-pays fork()+pickle per shard, so it gets a laxer
-#: floor — its committed metric is the slim column-pipe payload size
-EV_FORK_FLOOR = 1.2
-
-
-def _ev_registry():
-    """A fresh registry of ``EV_TENANTS`` MobileNetV2 tenants, each pinned
-    to its own disjoint 3-node slice (explicit assignment, so every core
-    sees the identical placement and the sharder finds the groups)."""
-    from repro.core.tenancy import TenantRegistry, TenantTraffic
-    from repro.core.traffic import DeterministicArrivals
-
-    cluster = make_synthetic_cluster(EV_NODES, seed=7)
-    nids = list(cluster.nodes)
-    reg = TenantRegistry(cluster)
-    g = mobilenetv2_graph()
-    per_tenant = EV_REQUESTS // EV_TENANTS
-    for i in range(EV_TENANTS):
-        reg.add(f"t{i}", ModelPartitioner(g),
-                traffic=TenantTraffic(
-                    num_requests=per_tenant, seed=i,
-                    concurrency=EV_CONCURRENCY,
-                    arrivals=DeterministicArrivals.at_rate(EV_RATE_RPS)),
-                num_partitions=3,
-                assignment=nids[3 * i:3 * i + 3])
-    return reg
-
-
-def _evc_registry():
-    """The contended variant of :func:`_ev_registry`: identical disjoint
-    slices, open-loop Poisson storms well past each slice's capacity."""
-    from repro.core.tenancy import TenantRegistry, TenantTraffic
-
-    cluster = make_synthetic_cluster(EV_NODES, seed=7)
-    nids = list(cluster.nodes)
-    reg = TenantRegistry(cluster)
-    g = mobilenetv2_graph()
-    per_tenant = EV_REQUESTS // EV_TENANTS
-    for i in range(EV_TENANTS):
-        reg.add(f"t{i}", ModelPartitioner(g),
-                traffic=TenantTraffic(
-                    num_requests=per_tenant, seed=i,
-                    concurrency=EVC_CONCURRENCY,
-                    arrivals=PoissonArrivals(rate_rps=EVC_RATE_RPS,
-                                             seed=100 + i)),
-                num_partitions=3,
-                assignment=nids[3 * i:3 * i + 3])
-    return reg
-
-
-def _eva_registry():
-    """The adaptive variant: per-tenant AdaptationControllers, each scoped
-    to its own disjoint 3-node ``nodes=`` closure (planner-placed, so the
-    sharder derives the groups from the declared migration closures)."""
-    from repro.core.tenancy import TenantRegistry, TenantTraffic
-
-    cluster = make_synthetic_cluster(EVA_TENANTS * EVA_NODES_PER, seed=7)
-    nids = list(cluster.nodes)
-    reg = TenantRegistry(cluster)
-    g = mobilenetv2_graph()
-    per_tenant = EVA_REQUESTS // EVA_TENANTS
-    for i in range(EVA_TENANTS):
-        reg.add(f"t{i}", ModelPartitioner(g),
-                traffic=TenantTraffic(
-                    num_requests=per_tenant, seed=i,
-                    concurrency=EVA_CONCURRENCY,
-                    arrivals=PoissonArrivals(rate_rps=EVA_RATE_RPS,
-                                             seed=100 + i)),
-                num_partitions=3, method="planner", adaptive=True,
-                nodes=nids[EVA_NODES_PER * i:EVA_NODES_PER * (i + 1)])
-    return reg
-
-
-def eventspersec_rows():
-    """Heap oracle vs the time-wheel core (sharding off, then auto) on the
-    identical 16-tenant scenario. The unsharded fast row must reproduce
-    the oracle bit-for-bit with the same dispatched event count; the
-    sharded row must clear ``EV_SPEEDUP_FLOOR``× the oracle's events/sec
-    (both asserted here, so the committed numbers are load-bearing).
-
-    Then two sharded-vs-interleaved pairs on the operating points tenant
-    sharding targets: the contended storm (contended-chain fusion hot)
-    and the adaptive fleet (per-closure controllers free-running between
-    epoch barriers). Each sharded row must dispatch the identical event
-    count, reproduce the interleaved columns and adaptation logs
-    bit-for-bit, and clear ``EV_SHARD_FLOOR``× the interleaved fast
-    core's events/sec. A final forked row re-runs the contended storm
-    across worker processes, metering the slim column-pipe payload
-    (``pipe_bytes``) under the laxer ``EV_FORK_FLOOR``."""
-    from repro.core import engine as eng_mod
-    from repro.core import fastcore
-
-    rows = []
-    runs = {}
-    for label, core, shards in (("heap-oracle", "heap", "none"),
-                                ("fastcore", "fast", "none"),
-                                ("fastcore+shards", "fast", "auto")):
-        reg = _ev_registry()
-        cfg = EngineConfig(core=core, shards=shards)
-        t0 = time.perf_counter()
-        result = reg.run(name=label, engine=cfg)
-        wall_s = time.perf_counter() - t0
-        nev = (eng_mod.LAST_EVENT_COUNT if core == "heap"
-               else fastcore.LAST_EVENT_COUNT)
-        runs[label] = (result, nev, nev / wall_s)
-        rows.append(dict(
-            config=label,
-            tenants=EV_TENANTS,
-            nodes=EV_NODES,
-            num_requests=EV_REQUESTS,
-            events=nev,
-            wall_s=round(wall_s, 2),
-            events_per_sec=round(nev / wall_s, 0),
-        ))
-
-    oracle, fast, sharded = (runs[k] for k in
-                             ("heap-oracle", "fastcore", "fastcore+shards"))
-    for name, rep in oracle[0].reports.items():
-        assert fast[0].reports[name].columns.bitwise_equal(rep.columns), (
-            f"fast core drifted from the heap oracle on tenant {name!r}")
-        assert sharded[0].reports[name].columns.bitwise_equal(rep.columns), (
-            f"sharded fast core drifted from the oracle on tenant {name!r}")
-    assert fast[1] == oracle[1], (
-        f"unsharded fast core dispatched {fast[1]} events, "
-        f"oracle {oracle[1]} — the cores disagree on the event stream")
-    rows[1]["matches_heap_oracle"] = True
-
-    speedup = sharded[2] / oracle[2]
-    assert speedup >= EV_SPEEDUP_FLOOR, (
-        f"sharded fast core managed only {speedup:.1f}× the oracle's "
-        f"events/sec (floor {EV_SPEEDUP_FLOOR:.0f}×)")
-    rows[2]["matches_oracle_columns"] = True
-    rows[2]["speedup_vs_heap"] = round(speedup, 1)
-
-    def _measure(label, mk, shards, workers=0, tenants=EV_TENANTS,
-                 total=EV_REQUESTS):
-        reg = mk()
-        cfg = EngineConfig(core="fast", shards=shards, micro_batch=8,
-                           adaptive_batch=True, shard_workers=workers)
-        t0 = time.perf_counter()
-        result = reg.run(name=label, engine=cfg)
-        wall_s = time.perf_counter() - t0
-        nev = fastcore.LAST_EVENT_COUNT
-        rows.append(dict(
-            config=label,
-            tenants=tenants,
-            num_requests=total,
-            events=nev,
-            wall_s=round(wall_s, 2),
-            events_per_sec=round(nev / wall_s, 0),
-        ))
-        return result, nev, nev / wall_s
-
-    def _assert_pair(tag, base, shard, floor):
-        assert shard[1] == base[1], (
-            f"{tag}: sharded fast core dispatched {shard[1]} events, "
-            f"interleaved {base[1]} — the shard merge lost or invented "
-            f"events")
-        for name, rep in base[0].reports.items():
-            srep = shard[0].reports[name]
-            assert srep.columns.bitwise_equal(rep.columns), (
-                f"{tag}: sharded run drifted from interleaved on tenant "
-                f"{name!r}")
-            assert srep.adaptation == rep.adaptation, (
-                f"{tag}: sharded run drifted on tenant {name!r}'s "
-                f"adaptation log")
-        sp = shard[2] / base[2]
-        assert sp >= floor, (
-            f"{tag}: sharded fast core managed only {sp:.2f}× the "
-            f"interleaved core's events/sec (floor {floor:.1f}×)")
-        rows[-1]["matches_interleaved"] = True
-        rows[-1]["speedup_vs_interleaved"] = round(sp, 1)
-
-    contended_base = None
-    for tag, mk, tenants, total in (
-            ("contended", _evc_registry, EV_TENANTS, EV_REQUESTS),
-            ("adaptive", _eva_registry, EVA_TENANTS, EVA_REQUESTS)):
-        base = _measure(f"fastcore-{tag}", mk, "none",
-                        tenants=tenants, total=total)
-        shard = _measure(f"fastcore-{tag}+shards", mk, "auto",
-                         tenants=tenants, total=total)
-        _assert_pair(tag, base, shard, EV_SHARD_FLOOR)
-        if tag == "contended":
-            contended_base = base
-
-    # the forked lane on the contended storm: shards round-robin across
-    # worker processes and ship the slim per-group column state back over
-    # the pipe — metered here so pickle-payload regressions show up in
-    # the committed row
-    forked = _measure("fastcore-contended+shards-forked", _evc_registry,
-                      "auto", workers=EVC_WORKERS)
-    _assert_pair("contended-forked", contended_base, forked, EV_FORK_FLOOR)
-    assert fastcore.LAST_SHARD_PIPE_BYTES > 0, (
-        "forked sharded run shipped no column state over the pipe — "
-        "fork mode silently fell back to in-process")
-    rows[-1]["pipe_bytes"] = fastcore.LAST_SHARD_PIPE_BYTES
-    return rows
-
-
 # --- operator-DAG dataflow ----------------------------------------------------
 
 #: the dagsweep scenario: an MoE-style branched plan (trunk -> 2 asymmetric
@@ -947,7 +705,6 @@ def run(scale_requests: int = 100_000, write: bool = True,
         faultstorm=faultstorm_rows(),
         dagsweep=dagsweep_rows(),
         scale=scale_rows(scale_requests, budget_s=budget_s),
-        eventspersec=eventspersec_rows(),
         multitenant=multitenant_rows(
             budget_s=MT_WALL_BUDGET_S if budget_s is not None else None),
     )

@@ -7,8 +7,7 @@ open-loop sections are cheap and run at full size) and compares against the
 committed ``BENCH_pipeline.json`` baseline:
 
 * **Simulated metrics** (``table1`` + ``modes`` + ``openloop`` sections, the
-  stage count of the scale plans, the dispatched event counts of the
-  ``eventspersec`` section, and the full ``multitenant`` section —
+  stage count of the scale plans, and the full ``multitenant`` section —
   per-tenant goodput, migrations, and the arbitration-beats-independent
   margin) must match the baseline exactly — the discrete-event simulation is
   bit-reproducible, so any difference is a timing-model or engine drift, not
@@ -59,17 +58,9 @@ SCALE_VOLATILE_FIELDS = {"num_requests", "wall_s", "sim_req_per_wall_s",
 #: every simulated metric (per-tenant goodput, migrations, the
 #: arbitration-beats-independent margin) is compared exactly
 MT_VOLATILE_FIELDS = {"wall_s", "sim_req_per_wall_s"}
-#: eventspersec rows: the dispatched event count is simulated (exact); the
-#: wall clock, the derived rates, the measured speedup ratios, and the
-#: fork-pipe payload size are not — the ≥10×-vs-heap and ≥2×-vs-interleaved
-#: floors are asserted inside the bench itself, so a collapsed speedup
-#: still fails the gate (as a bench error, not a metric diff)
-EV_VOLATILE_FIELDS = {"wall_s", "events_per_sec", "speedup_vs_heap",
-                      "speedup_vs_interleaved", "pipe_bytes"}
 #: sections with wall-clock-volatile rows: {section: its volatile fields};
 #: rows carrying ``sim_req_per_wall_s`` also get the wall-rate band
 WALL_SECTIONS = {"scale": frozenset(SCALE_VOLATILE_FIELDS),
-                 "eventspersec": frozenset(EV_VOLATILE_FIELDS),
                  "multitenant": frozenset(MT_VOLATILE_FIELDS)}
 
 

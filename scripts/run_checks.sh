@@ -3,15 +3,12 @@
 #
 #   1. tier-1 pytest        (the suite every PR must keep green; includes
 #                            the seeded fault sweep in tests/test_faults.py —
-#                            conservation + cross-core bit parity under
+#                            conservation + golden fingerprints under
 #                            injected crashes/losses/stragglers — the
 #                            DAG chain-equivalence sweep in tests/test_dag.py,
-#                            and the sharding property tests in
-#                            tests/test_engine_parity.py: sharded ==
-#                            interleaved == heap oracle on adaptive,
-#                            arbitrated, contended, and node-sliced draws
-#                            (shards="auto" is the engine default, so the
-#                            whole parity sampler sweeps the sharded path);
+#                            and the sampled engine configurations of
+#                            tests/test_engine_parity.py, each held to the
+#                            golden fingerprint recorded for it;
 #                            --fast keeps each suite's tier-1 prefix and
 #                            skips the slow-marked bulk sweeps)
 #   2. check_docs.py        (public-API docstring lint for repro.core)
@@ -19,23 +16,18 @@
 #                            reduced benchmark vs committed BENCH_pipeline.json,
 #                            including the multitenant section — 3-tenant
 #                            shared-heap scale row + the arbitration-beats-
-#                            independent-replanning goodput comparison — the
-#                            dagsweep section: branched early-exit plans
-#                            + the cascade-beats-expensive-only assertion —
-#                            and the eventspersec section: heap-oracle vs
-#                            fast-core vs sharded rows plus the contended /
-#                            adaptive / forked sharding rows, whose ≥10×-vs-
-#                            heap and ≥2×-vs-interleaved floors assert inside
-#                            the bench itself)
+#                            independent-replanning goodput comparison — and
+#                            the dagsweep section: branched early-exit plans
+#                            + the cascade-beats-expensive-only assertion)
 #
 # Usage:  scripts/run_checks.sh [--skip-perf|--fast]
 #   --skip-perf  run only the tier-1 + docs gates; the perf gate
 #                re-runs the pipeline benchmark and takes ~2 min.
 #   --fast       like --skip-perf, but also deselect `slow` tests (the
-#                heavy generative sweeps, e.g. the full differential
-#                engine-parity suite, and the ~8-min moe-sharded
-#                subprocess compiles) — cuts the ~19-min tier to a few
-#                minutes; CI runs the un-flagged full gate.
+#                heavy generative sweeps, e.g. the full engine-parity
+#                suite, and the ~8-min multi-device MoE subprocess
+#                compiles) — cuts the ~19-min tier to a few minutes; CI
+#                runs the un-flagged full gate.
 
 set -euo pipefail
 cd "$(dirname "$0")/.."

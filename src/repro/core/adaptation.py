@@ -268,9 +268,7 @@ class AdaptationController:
         Every controller input — drift detection, the planner candidates,
         the capacity baseline — runs on the filtered view, so a closed
         tenant can never observe (or migrate onto) a node outside its
-        declared subset. That invariant is what lets the fast core shard
-        adaptive tenants: disjoint closures prove disjoint reachable node
-        sets. Identity when no closure was declared."""
+        declared subset. Identity when no closure was declared."""
         allowed = getattr(self.pipeline, "allowed_nodes", None)
         if allowed is None:
             return stats

@@ -145,45 +145,23 @@ class EngineConfig:
     The default configuration (``legacy``, 1, ``isolated``) reproduces the
     seed loop's per-request timing bit-for-bit.
 
-    ``core`` selects the event-loop implementation: ``"fast"`` (default)
-    is the time-wheel core (``core.fastcore``) with fused chains and
-    columnar poll ticks; ``"heap"`` is the original heap loop, kept as
-    the differential oracle — the two produce bit-identical results
-    (``tests/test_engine_parity.py``). ``shards="auto"`` (the default)
-    lets the fast core run reachable-disjoint tenant groups on
-    independent wheels: controller-less groups free-run to completion
-    (sampling series merge-extended to the fleet horizon afterwards),
-    groups under adaptation controllers or a capacity arbiter run
-    between epoch barriers with one fleet-wide poll tick — either way
-    every report field stays bit-identical to the interleaved run.
-    ``shard_workers > 1`` additionally forks that many worker processes
-    for free-running groups. ``shards="none"`` is a debug escape hatch
-    that pins the single interleaved wheel (useful when bisecting the
-    sharded merge itself); it is never required for correctness.
-
     ``faults`` attaches a :class:`core.faults.FaultConfig`: seeded
     fault injection (crash/restart, transfer loss, execution failures,
     stragglers) plus the retry/timeout/hedge/shed lifecycle, handled by
-    the shared ``core.faults.FaultRuntime`` in both cores. Requires the
+    ``core.faults.FaultRuntime`` inside the event loop. Requires the
     isolated fabric (the shared-fabric flow state has no loss/requeue
-    semantics yet) and disables sharding and the eager fast path.
+    semantics yet) and disables the eager fast path.
     """
     transfer: str = "legacy"
     micro_batch: int = 1
     fabric: str = "isolated"
     adaptive_batch: bool = False
-    core: str = "fast"
-    shards: str = "auto"
-    shard_workers: int = 0
     faults: Optional[FaultConfig] = None
 
     def __post_init__(self):
         assert self.transfer in TRANSFER_MODES, self.transfer
         assert self.micro_batch >= 1, self.micro_batch
         assert self.fabric in FABRIC_MODES, self.fabric
-        assert self.core in ("fast", "heap"), self.core
-        assert self.shards in ("none", "auto"), self.shards
-        assert self.shard_workers >= 0, self.shard_workers
         assert self.faults is None or self.fabric == "isolated", \
             "fault injection requires the isolated fabric"
 
@@ -331,8 +309,8 @@ class StageTable:
                        (nodes[self.placement_src[part.index + 1]]
                         if part.index < last else None))
             for part in parts]
-        #: True for linear plans — the fast core fuses only chain tables,
-        #: and every DAG-only handler branch keys off ``succs is not None``
+        #: True for linear plans; every DAG-only handler branch keys off
+        #: ``succs is not None``
         self.chain = self.plan.stage_dag is None
         if not self.chain:
             dag = self.plan.stage_dag
@@ -660,8 +638,8 @@ class PipelineEngine:
         drift trigger)."""
         stream = _Stream(self, num_requests, name, repeat_rate, seed,
                          concurrency, arrivals)
-        leftover, fabric = _dispatch_streams(self.pipe.cluster, [stream],
-                                             cfg, scenario)
+        leftover, fabric = _run_event_streams(self.pipe.cluster, [stream],
+                                              cfg, scenario)
         return self._report(
             name, stream.cols, stream.total_net, num_requests, leftover,
             queue_depth=(np.asarray(stream.qd_t, dtype=np.float64),
@@ -749,10 +727,8 @@ def _committed_excluding(streams: Sequence["_Stream"],
                              exclude=me.pipe.tenant) or None
 
 
-#: events dispatched by the most recent heap-oracle run
-#: (``_run_event_streams``); the fast core keeps its own counter in
-#: ``fastcore.LAST_EVENT_COUNT``, and a parity pair of runs reports equal
-#: counts — fused chain steps are counted as the heap pops they replace
+#: events dispatched by the most recent event-loop run
+#: (``_run_event_streams``); the eager fast path dispatches none
 LAST_EVENT_COUNT = 0
 
 
@@ -761,9 +737,9 @@ def _exit_draw(seed: int, r: int, exit_heads) -> int:
     heads in layer order drawing one uniform per (stream seed, request,
     head layer), return the first head whose draw lands under its exit
     probability, or -1 to continue. ``SeedSequence``-keyed so the outcome
-    is a pure function of identity — independent of event order, core,
-    micro-batching, or sharding (the exit-rate determinism property the
-    DAG suite pins)."""
+    is a pure function of identity — independent of event order and
+    micro-batching (the exit-rate determinism property the DAG suite
+    pins)."""
     for head, prob in exit_heads:
         u = np.random.SeedSequence((seed, r, head)).generate_state(1)[0]
         if u / 4294967296.0 < prob:
@@ -792,8 +768,7 @@ def _check_dag_streams(streams: Sequence["_Stream"], cfg) -> None:
 
 def _dag_cdone(node, st, batch: List[int], t: float, mode: str, s,
                push, finish_request, try_start) -> None:
-    """Completion continuation of a DAG stage (both cores dispatch here,
-    so DAG runs are core-parity by construction): draw the stage's exit
+    """Completion continuation of a DAG stage: draw the stage's exit
     heads per request, finish early-exiters and — on a terminal stage —
     the survivors, then forward one coalesced message per outgoing edge
     under the run's transfer model. A join target releases only once all
@@ -887,21 +862,6 @@ def _trim_dynamic(streams: Sequence["_Stream"]) -> None:
         s.n = s.next_r
 
 
-def _dispatch_streams(cluster, streams: Sequence["_Stream"],
-                      cfg: EngineConfig,
-                      scenario: Optional[Sequence[ScenarioEvent]],
-                      arbiter=None):
-    """Route a stream set to the configured event core: the time-wheel
-    fast core (default) or the heap oracle. Lazy import — ``fastcore``
-    imports this module at load time."""
-    if cfg.core == "fast":
-        from repro.core import fastcore
-        return fastcore.run_fast_streams(cluster, streams, cfg, scenario,
-                                         arbiter)
-    return _run_event_streams(cluster, streams, cfg, scenario,
-                              arbiter=arbiter)
-
-
 def _run_event_streams(cluster, streams: Sequence["_Stream"],
                        cfg: EngineConfig,
                        scenario: Optional[Sequence[ScenarioEvent]],
@@ -977,12 +937,7 @@ def _run_event_streams(cluster, streams: Sequence["_Stream"],
     fr = None
     if cfg.faults is not None:
         from repro.core.faults import FaultRuntime
-
-        def _fault_push(at: float, lane: int, pl) -> None:
-            heapq.heappush(heap, (at, lane, next(seq), pl))
-
-        fr = FaultRuntime(cluster, streams, cfg, _fault_push,
-                          arbiter=arbiter)
+        fr = FaultRuntime(cluster, streams, cfg, _push, arbiter=arbiter)
         fr.begin(t0)
 
     def try_start(node, now: float) -> None:
@@ -1125,9 +1080,8 @@ def _run_event_streams(cluster, streams: Sequence["_Stream"],
             clock.now_ms = t
 
         if fr is not None and prio != _P_POLL:
-            # fault mode: the shared lifecycle runtime handles every
-            # request-path event (poll ticks stay per-core — the compact
-            # and object paths are already parity-proven)
+            # fault mode: the lifecycle runtime handles every
+            # request-path event; poll ticks stay in this loop
             fr.dispatch(prio, t, payload)
             continue
 
@@ -1458,8 +1412,8 @@ class MultiTenantEngine:
                 assert s.escalate_to.n >= s.n, (
                     f"cascade target {s.escalate_to.name!r} capacity "
                     f"{s.escalate_to.n} < source demand {s.n}")
-        leftover, fabric = _dispatch_streams(self.cluster, streams, cfg,
-                                             scenario, arbiter=arbiter)
+        leftover, fabric = _run_event_streams(self.cluster, streams, cfg,
+                                              scenario, arbiter=arbiter)
         clock = self.cluster.clock
         clock.now_ms = max([clock.now_ms]
                            + [float(s.cols.finish_ms.max())

@@ -18,16 +18,13 @@ closes that hole with two pieces:
     optional deadline-aware load shedding at admission.
 
 :class:`FaultRuntime`
-    The lifecycle state machine itself. Both event cores
-    (``engine._run_event_streams`` — the heap oracle — and
-    ``fastcore._run_group`` — the time wheel) construct one runtime and
-    forward every non-poll event to :meth:`FaultRuntime.dispatch`; the
-    runtime's handlers are the oracle's handler bodies with the fault
-    draws and recovery transitions spliced in, and the only core-specific
-    dependency is a ``push(time, lane, payload)`` closure. Faulted runs
-    are therefore bit-for-bit identical across cores *by construction* —
-    the same code object produces every float in the same order — and the
-    parity suite (``tests/test_faults.py``) asserts it anyway.
+    The lifecycle state machine itself. The event loop
+    (``engine._run_event_streams``) constructs one runtime and forwards
+    every non-poll event to :meth:`FaultRuntime.dispatch`; the runtime's
+    handlers are the loop's handler bodies with the fault draws and
+    recovery transitions spliced in, and its only dependency on the loop
+    is a ``push(time, lane, payload)`` closure. ``tests/test_faults.py``
+    pins sampled fault storms to golden fingerprints.
 
 Design rules the implementation must keep (and why):
 
@@ -38,20 +35,19 @@ Design rules the implementation must keep (and why):
   bit-identical to ``faults=None``.
 * **Fault events are ordinary events.** Crash/restart chains, per-stage
   timeouts, retry re-deliveries, and hedge completions ride the existing
-  heap/wheel lanes (``_P_SCENARIO`` for control, ``_P_ARRIVE`` for
-  deliveries, ``_P_CDONE`` for executions), so the cores' pop order —
-  and hence parity — needs no new machinery.
+  heap lanes (``_P_SCENARIO`` for control, ``_P_ARRIVE`` for
+  deliveries, ``_P_CDONE`` for executions), so the loop's pop order
+  needs no new machinery.
 * **Crash epochs, not object death.** A crash bumps
   ``EdgeNode.crash_epoch``; an execution started under an older epoch is
   *killed*: its completion event still fires but must not touch node
   state (the node may have restarted and be running other work).
 * **Forced polls only.** Recovery decisions (alternate-node re-score,
-  redeploy) always read ``monitor.poll(force=True)`` — the fast core's
-  compact poll ticks leave snapshot objects stale, so an interval-gated
-  read would diverge between cores.
+  redeploy) always read ``monitor.poll(force=True)``, never an
+  interval-gated snapshot that may predate the failure.
 * **Conservation.** Every request terminates in exactly one of
   {done, shed, failed-with-reason}; :meth:`FaultRuntime.finalize`
-  asserts the three counts partition the stream in both cores.
+  asserts the three counts partition the stream.
 
 Request lifecycle (states are per request, transitions are events)::
 
@@ -82,8 +78,8 @@ STATUS_FAILED = 2
 
 #: consecutive crash/restart dispatches with no request progress before
 #: the runtime declares the run wedged (a self-perpetuating crash chain
-#: must never spin a drained stream forever — both cores raise, so a
-#: lifecycle bug fails loudly and identically instead of hanging)
+#: must never spin a drained stream forever — the runtime raises, so a
+#: lifecycle bug fails loudly instead of hanging)
 _SPIN_LIMIT = 100_000
 
 
@@ -233,18 +229,15 @@ class _StreamFaultState:
 
 
 class FaultRuntime:
-    """The fault-mode request lifecycle, shared verbatim by both event
-    cores.
+    """The fault-mode request lifecycle.
 
-    A core constructs one runtime per run (when ``cfg.faults`` is set),
-    calls :meth:`begin` after its setup phase, forwards every non-poll
-    event to :meth:`dispatch` instead of its own handler chain, loops
-    until :attr:`terminated` reaches the stream total, and calls
+    The event loop constructs one runtime per run (when ``cfg.faults`` is
+    set), calls :meth:`begin` after its setup phase, forwards every
+    non-poll event to :meth:`dispatch` instead of its own handler chain,
+    loops until :attr:`terminated` reaches the stream total, and calls
     :meth:`finalize` where the fault-free path would run its conservation
-    check. The only core-specific behavior is the injected ``push``
-    closure; everything else — including every RNG draw and float
-    expression — is this class, which is what makes faulted runs
-    bit-identical across cores."""
+    check. The loop injects only the ``push`` closure; every RNG draw and
+    float expression of a faulted run is this class."""
 
     def __init__(self, cluster, streams: Sequence, cfg,
                  push: Callable[[float, int, object], None], arbiter=None):
@@ -284,7 +277,7 @@ class FaultRuntime:
     def begin(self, t0: float) -> None:
         """Arm the crash processes: one exponential up-time draw per
         target node, in sorted node-id order (the deterministic draw
-        order the parity suite replays)."""
+        order the golden fingerprints replay)."""
         fc = self.fc
         if fc.crash_mtbf_ms <= 0.0:
             return
@@ -299,7 +292,7 @@ class FaultRuntime:
 
     def dispatch(self, prio: int, t: float, payload) -> None:
         """Handle one popped event (any lane except the poll tick, which
-        stays core-specific). The cores call this instead of their own
+        stays in the event loop). The loop calls this instead of its own
         handler chain when fault mode is on."""
         if prio == self.P_SCENARIO:
             if isinstance(payload, ScenarioEvent):
@@ -653,7 +646,7 @@ class FaultRuntime:
 
     def _pick_alt(self, s, exclude_node) -> Optional[object]:
         """Scheduler re-score for a recovery dispatch: force-poll the
-        stream's monitor (fresh snapshots in both cores) and ask for the
+        stream's monitor (fresh snapshots) and ask for the
         best-scoring online node that is not the failed one and is
         engine-idle right now. None when nothing qualifies."""
         snaps = s.monitor.poll(force=True)
@@ -968,14 +961,13 @@ def account_stream_deaths(stream, now: float) -> None:
     """Account requests stranded by a planned ``ScenarioEvent`` node death
     on a *fault-free* run (``faults=None``).
 
-    Historically both cores raised ``RuntimeError("... lost in flight")``
+    Historically the engine raised ``RuntimeError("... lost in flight")``
     whenever the event queue drained with work still queued on a node a
     scenario killed. With no fault layer armed there is no retry budget to
     consult, but crashing the whole run over a scenario the caller asked
     for is wrong: the stranded requests are marked ``STATUS_FAILED`` with
     reason ``node-lost`` and the run completes with honest accounting.
-    Shared by both cores so the resulting columns and ``fstats`` dict are
-    bit-identical. Unfinished requests are identified by
+    Unfinished requests are identified by
     ``finish_ms == 0.0`` — real finishes are at least one scheduling
     overhead past a non-negative submit time, so 0.0 is unreachable.
     """

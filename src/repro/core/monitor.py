@@ -11,8 +11,8 @@ node per poll and report the overhead (paper: <= 1% CPU).
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from dataclasses import dataclass
+from typing import Dict, List
 
 from repro.core.cluster import EdgeCluster, EdgeNode
 
@@ -128,75 +128,10 @@ class ResourceMonitor:
             stability=stab,
         )
 
-    def poll_compact(self) -> List[EdgeNode]:
-        """Snapshot-free poll tick for the fast event core
-        (``core.fastcore``), used only for streams with no adaptation
-        controller — the only consumers of :class:`NodeStats` snapshots
-        and history are adaptation triggers and forced repair polls (which
-        re-poll with ``force=True`` and so rebuild identical snapshots
-        from the identical node state).
-
-        Side effects are bit-identical to :meth:`poll`: the poll stamp and
-        counter, the per-node overhead charge in node order, the per-node
-        ``cpu_busy_ms`` window reset, and offline detection. What is
-        skipped is only the *allocation* — ~N ``NodeStats`` objects and
-        history appends per simulated second that nobody would read.
-        Returns the online nodes (same order as ``online_stats``) for
-        ``TaskScheduler.select_node_compact``. Caller owns the interval
-        gate, exactly like the engine's poll handler."""
-        self.last_poll_ms = self.cluster.clock.now_ms
-        self.polls += 1
-        online: List[EdgeNode] = []
-        seen = self._offline_seen
-        for node in self.cluster.nodes.values():
-            self.overhead_ms += MONITOR_COST_MS_PER_POLL
-            node.cpu_busy_ms = 0.0
-            if node.online:
-                online.append(node)
-            elif node.node_id not in seen:
-                seen.add(node.node_id)
-        return online
-
     def online_stats(self) -> List[NodeStats]:
         """Fresh-enough snapshots of the currently-online nodes."""
         self.poll()
         return [s for s in self.snapshots.values() if s.online]
-
-    def poll_closure(self, allowed) -> List[NodeStats]:
-        """Closure-local poll tick for the fast event core's epoch-barrier
-        coordinator: builds ``NodeStats`` snapshots and history only for
-        the ``allowed`` node closure of a stream's declared ``nodes=``
-        subset — the only stats its adaptation controller and planner can
-        ever read (``AdaptationController._closure_stats`` filters every
-        consumer through the same set, and the scheduler/decision counters
-        the rest of a fleet-wide poll would feed are not part of the
-        engine's parity surface). Fleet-wide side effects stay bit-exact
-        with :meth:`poll`: the per-node overhead charge in node order
-        (``monitor_overhead_pct`` is compared bit-for-bit against the heap
-        oracle), the ``cpu_busy_ms`` window resets, and offline detection.
-        Caller owns the interval gate. Returns the closure's online stats
-        (the shape ``TaskScheduler.select_node`` takes)."""
-        now = self.cluster.clock.now_ms
-        window = max(now - self.last_poll_ms, POLL_INTERVAL_MS)
-        self.last_poll_ms = now
-        self.polls += 1
-        snaps: Dict[str, NodeStats] = {}
-        seen = self._offline_seen
-        for node in self.cluster.nodes.values():
-            self.overhead_ms += MONITOR_COST_MS_PER_POLL
-            if node.node_id in allowed:
-                stat = self._stat(node, window)   # resets cpu_busy_ms
-                snaps[node.node_id] = stat
-                h = self.history.setdefault(node.node_id, [])
-                h.append(stat)
-                if len(h) > HISTORY_WINDOW:
-                    h.pop(0)
-            else:
-                node.cpu_busy_ms = 0.0
-            if not node.online and node.node_id not in seen:
-                seen.add(node.node_id)
-        self.snapshots = snaps
-        return [s for s in snaps.values() if s.online]
 
     def sustained_overload(self, node_id: str, polls: int,
                            threshold: float) -> bool:

@@ -139,10 +139,11 @@ class RequestColumns:
 
     def bitwise_equal(self, other: "RequestColumns") -> bool:
         """Exact (bit-for-bit, no tolerance) equality of every column —
-        the differential-parity predicate used by the engine-parity suite
-        and the events-per-second benchmark to compare a fast-core run
-        against the heap oracle. NaN-free by construction (columns hold
-        simulated times/counters), so ``array_equal`` is exact equality."""
+        the predicate the engine tests use to compare two runs that must
+        agree (engine vs ``run_legacy``, a chain vs the same chain written
+        as a DAG, a run vs its repeat). NaN-free by construction (columns
+        hold simulated times/counters), so ``array_equal`` is exact
+        equality."""
         if len(self) != len(other):
             return False
         return all(np.array_equal(getattr(self, f), getattr(other, f))
@@ -452,9 +453,7 @@ class DistributedInference:
         self.partitioner = partitioner
         # optional placement closure: when set, planning, deployment, and
         # (through the AdaptationController) every future migration are
-        # restricted to this node subset. This is what makes an adaptive
-        # tenant shardable — the fast core can prove two tenants can never
-        # touch the same node only if their closures are disjoint.
+        # restricted to this node subset.
         if nodes is not None:
             known = set(cluster.nodes)
             unknown = set(nodes) - known

@@ -181,59 +181,6 @@ class TaskScheduler:
             self.task_counts[best] = self.task_counts.get(best, 0) + 1
         return best
 
-    def select_node_compact(self, nodes, req: Optional[TaskRequirements]
-                            = None) -> Optional[str]:
-        """:meth:`select_node` over *live online* ``EdgeNode`` objects —
-        the fast event core's snapshot-free poll tick (paired with
-        ``ResourceMonitor.poll_compact``). Every float is produced by the
-        same expression on the same inputs as the ``NodeStats`` path
-        (Eq. 5's availability terms are inlined from the snapshot
-        properties), and every side effect (decision/overhead counters,
-        skip counts in node order, the winner's queue-count bump) is
-        applied identically — so a run is bit-for-bit equal whichever
-        path polls. Only the intermediate ``NodeStats``/``NodeScore``
-        allocations are skipped. ``nodes`` must be the online subset in
-        cluster order, exactly what ``poll_compact`` returns."""
-        req = req or TaskRequirements()
-        self.decisions += 1
-        self.overhead_ms += SCHEDULING_OVERHEAD_MS
-        tmax = max((t for h in self.exec_history.values() for t in h),
-                   default=0.0)
-        w_r = self.weights["resource"]
-        w_l = self.weights["load"]
-        w_p = self.weights["perf"]
-        w_b = self.weights["balance"]
-        skips = self.skip_counts
-        best, best_score = None, 0.0
-        for node in nodes:
-            load = node.current_load
-            if load > self.load_threshold:
-                skips["overloaded"] = skips.get("overloaded", 0) + 1
-                continue
-            prof = node.profile
-            if prof.net_latency_ms > self.latency_threshold_ms:
-                skips["high-latency"] = skips.get("high-latency", 0) + 1
-                continue
-            cpu_avail = prof.cpu * max(0.0, 1.0 - load)
-            mem_avail = max(0.0, prof.mem_mb
-                            - node.mem_used_bytes / (1024 * 1024))
-            if cpu_avail < req.cpu or mem_avail < req.mem_mb:
-                skips["insufficient-resources"] = (
-                    skips.get("insufficient-resources", 0) + 1)
-                continue
-            s_r = (cpu_avail / max(req.cpu, 1e-9)
-                   + mem_avail / max(req.mem_mb, 1e-9)) / 2.0
-            s_l = 1.0 - load
-            s_p = self._perf_score(node.node_id, tmax)
-            s_b = self._balance_score(node.node_id)
-            total = (w_r * min(s_r, 1.0) + w_l * s_l
-                     + w_p * s_p + w_b * s_b)
-            if total > best_score:
-                best, best_score = node.node_id, total
-        if best is not None:
-            self.task_counts[best] = self.task_counts.get(best, 0) + 1
-        return best
-
     # --- history feedback -------------------------------------------------------
 
     def task_completed(self, node_id: str, exec_ms: float,

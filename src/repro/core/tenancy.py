@@ -40,7 +40,7 @@ pinned bit-for-bit by ``tests/test_tenancy.py``.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, TYPE_CHECKING
+from typing import Dict, Optional, Sequence, TYPE_CHECKING
 
 from repro.core.adaptation import ScenarioEvent
 from repro.core.cluster import EdgeCluster
@@ -176,44 +176,6 @@ def committed_budgets(tenants, exclude=None) -> Dict[str, float]:
     return out
 
 
-def disjoint_node_groups(node_sets) -> List[List[int]]:
-    """Partition node-id sets into groups that share no node — union-find
-    over shared nodes. Returns index groups, each sorted, ordered by
-    smallest member. The fast event core feeds this either bare placement
-    node sets (immobile tenants) or *reachable* sets (placement plus the
-    ``nodes=`` migration closure of an adaptive tenant): two tenants in
-    different groups can never contend for an engine, queue slot, or
-    (isolated-fabric) link — not even after migrations — which is what
-    lets ``core.fastcore`` run each group on an independent event wheel."""
-    node_sets = list(node_sets)
-    parent = list(range(len(node_sets)))
-
-    def find(i: int) -> int:
-        while parent[i] != i:
-            parent[i] = parent[parent[i]]
-            i = parent[i]
-        return i
-
-    node_owner: Dict[str, int] = {}
-    for i, nodes in enumerate(node_sets):
-        for nid in nodes:
-            j = node_owner.get(nid)
-            if j is None:
-                node_owner[nid] = i
-            else:
-                parent[find(i)] = find(j)
-    groups: Dict[int, List[int]] = {}
-    for i in range(len(node_sets)):
-        groups.setdefault(find(i), []).append(i)
-    return [groups[k] for k in sorted(groups)]
-
-
-def disjoint_placement_groups(placements) -> List[List[int]]:
-    """Placement-map (stage -> node id) form of
-    :func:`disjoint_node_groups`."""
-    return disjoint_node_groups([set(p.values()) for p in placements])
-
-
 class TenantRegistry:
     """The tenants sharing one ``EdgeCluster``, plus the cross-tenant
     budget views (committed memory, per-node time) that make joint
@@ -279,7 +241,7 @@ class TenantRegistry:
         predicted-net-gain migration per control tick; without it every
         tenant's controller acts independently. A registry holding exactly
         one tenant dispatches through the tenant's own pipeline — the
-        identical code path (fast parity path included) a direct
+        identical code path (the eager fast path included) a direct
         ``DistributedInference.run`` takes, so single-tenant behavior is
         bit-for-bit unchanged by the tenancy layer.
         """

@@ -9,9 +9,9 @@ The lockdown has four parts:
    micro-batch × fabric × arrivals × cache × tenants, then expresses
    each model two ways: the implicit chain (``preds=None``) and the same
    chain written as an explicit operator DAG (``preds=(i-1,)``,
-   ``exit_prob=0.0``).  All four runs (two graphs × two cores) must be
-   **bit-for-bit identical** — the DAG generalization is only allowed to
-   exist where the graph is genuinely not a chain.
+   ``exit_prob=0.0``).  Both runs must be **bit-for-bit identical** —
+   the DAG generalization is only allowed to exist where the graph is
+   genuinely not a chain.
 2. **DAG properties** (hypothesis-or-shim) — conservation of requests
    under early exits, seeded exit determinism against a direct
    ``_exit_draw`` recomputation, and topological validity of
@@ -19,11 +19,11 @@ The lockdown has four parts:
 3. **Join timing** — a single request through a branched plan on
    distinct nodes finishes exactly when the engine's own stage table
    says the slowest predecessor chain allows (bit-exact float
-   recomputation, both cores).
-4. **Fusion refusal + cascade** — the fast core must not fuse DAG
-   tables (event counts pin to the heap oracle on a branched plan), and
-   a two-model cascade escalates exactly the cheap tenant's misses into
-   the expensive tenant at their finish times.
+   recomputation).
+4. **Pinned branched run + cascade** — a branched plan dispatches the
+   golden event count and fingerprint, and a two-model cascade escalates
+   exactly the cheap tenant's misses into the expensive tenant at their
+   finish times.
 
 A failing sampled config prints its seed and index; replay with
 ``_config_at(SAMPLER_SEED, index)``."""
@@ -32,12 +32,11 @@ import random
 
 import numpy as np
 import pytest
-from conftest import given, settings, st
+from conftest import fingerprint, given, settings, st
 
 from repro.core.cluster import make_paper_cluster, make_synthetic_cluster
 from repro.core.engine import EngineConfig, StageTable
 from repro.core import engine as eng_mod
-from repro.core import fastcore
 from repro.core.partitioner import ModelPartitioner, build_stage_dag
 from repro.core.pipeline import DistributedInference
 from repro.core.tenancy import TenantRegistry, TenantTraffic
@@ -131,15 +130,14 @@ def _make_arrivals(cfg: dict, tenant_idx: int):
     return TraceArrivals(np.cumsum(gaps))
 
 
-def _run(core: str, graph: ModelGraph, cfg: dict):
-    """Run ``graph`` under ``cfg`` on ``core``; returns
-    (MultiTenantReport, event count) or a stringified failure (every
-    graph × core combination must then fail identically)."""
+def _run(graph: ModelGraph, cfg: dict):
+    """Run ``graph`` under ``cfg``; returns (MultiTenantReport, event
+    count) or a stringified failure (both graphs must then fail
+    identically)."""
     cluster = make_synthetic_cluster(cfg["n_nodes"],
                                      seed=cfg["cluster_seed"] % 1000)
     reg = TenantRegistry(cluster)
     eng_mod.LAST_EVENT_COUNT = None
-    fastcore.LAST_EVENT_COUNT = None
     try:
         for i in range(cfg["n_tenants"]):
             reg.add(f"t{i}", ModelPartitioner(graph),
@@ -153,14 +151,11 @@ def _run(core: str, graph: ModelGraph, cfg: dict):
                     use_cache=cfg["use_cache"])
         engine_cfg = EngineConfig(
             transfer=cfg["transfer"], micro_batch=cfg["micro_batch"],
-            fabric=cfg["fabric"], adaptive_batch=cfg["adaptive_batch"],
-            core=core)
+            fabric=cfg["fabric"], adaptive_batch=cfg["adaptive_batch"])
         result = reg.run(engine=engine_cfg)
-    except Exception as e:   # all combinations must fail the same way
+    except Exception as e:   # both graphs must fail the same way
         return f"{type(e).__name__}: {e}", None
-    nev = (eng_mod.LAST_EVENT_COUNT if core == "heap"
-           else fastcore.LAST_EVENT_COUNT)
-    return result, nev
+    return result, eng_mod.LAST_EVENT_COUNT
 
 
 def _assert_chain_equivalence(index: int):
@@ -171,38 +166,34 @@ def _assert_chain_equivalence(index: int):
     plain, dagged = _chain_pair(cfg)
     assert dagged.is_chain, (
         f"explicit (i-1)-preds chain failed to normalize\n{repro}")
-    runs = [(g, core) for g in (plain, dagged) for core in ("heap", "fast")]
-    results = [_run(core, g, cfg) for g, core in runs]
-    ref, ref_ev = results[0]
-    for (g, core), (res, nev) in zip(runs[1:], results[1:]):
-        who = f"graph={'plain' if g is plain else 'dagged'} core={core}"
-        if isinstance(ref, str) or isinstance(res, str):
-            assert ref == res, (
-                f"failure modes disagree for {who} — reference: {ref!r}, "
-                f"got: {res!r}\n{repro}")
-            continue
-        assert ref_ev == nev, (
-            f"event counts differ for {who}: {ref_ev} vs {nev}\n{repro}")
-        assert set(ref.reports) == set(res.reports), repro
-        for name, h in ref.reports.items():
-            f = res.reports[name]
-            assert h.columns.bitwise_equal(f.columns), (
-                f"RequestColumns differ for tenant {name!r} ({who})"
-                f"\n{repro}")
-            assert h.batch_hist == f.batch_hist, f"{who}\n{repro}"
-            assert h.network_bytes == f.network_bytes, f"{who}\n{repro}"
-            hq, fq = h.queue_depth, f.queue_depth
-            assert (hq is None) == (fq is None), repro
-            if hq is not None:
-                assert (np.array_equal(hq[0], fq[0])
-                        and np.array_equal(hq[1], fq[1])), f"{who}\n{repro}"
-            assert h.fabric_stats == f.fabric_stats, f"{who}\n{repro}"
+    ref, ref_ev = _run(plain, cfg)
+    res, nev = _run(dagged, cfg)
+    if isinstance(ref, str) or isinstance(res, str):
+        assert ref == res, (
+            f"failure modes disagree for the dagged graph — reference: {ref!r}, "
+            f"got: {res!r}\n{repro}")
+        return
+    assert ref_ev == nev, (
+        f"event counts differ for the dagged graph: {ref_ev} vs {nev}\n{repro}")
+    assert set(ref.reports) == set(res.reports), repro
+    for name, h in ref.reports.items():
+        f = res.reports[name]
+        assert h.columns.bitwise_equal(f.columns), (
+            f"RequestColumns differ for tenant {name!r}\n{repro}")
+        assert h.batch_hist == f.batch_hist, repro
+        assert h.network_bytes == f.network_bytes, repro
+        hq, fq = h.queue_depth, f.queue_depth
+        assert (hq is None) == (fq is None), repro
+        if hq is not None:
+            assert (np.array_equal(hq[0], fq[0])
+                    and np.array_equal(hq[1], fq[1])), repro
+        assert h.fabric_stats == f.fabric_stats, repro
 
 
 @pytest.mark.parametrize("index", range(TIER1_CONFIGS))
 def test_chain_equivalence_tier1(index):
     """A chain written as an explicit DAG runs the original chain code
-    bit-for-bit, on both cores — the always-on degeneracy gate."""
+    bit-for-bit — the always-on degeneracy gate."""
     _assert_chain_equivalence(index)
 
 
@@ -229,7 +220,7 @@ def test_sampler_is_deterministic():
 def _expected_exit(seed: int, r: int, graph: ModelGraph) -> int:
     """Direct recomputation of request ``r``'s exit head: walk the exit
     heads in layer order, first successful seeded draw wins — the
-    engine must agree regardless of cuts, cores, or event order."""
+    engine must agree regardless of cuts or event order."""
     for e, l in enumerate(graph.layers):
         if l.exit_prob > 0.0:
             if eng_mod._exit_draw(seed, r, ((e, l.exit_prob),)) == e:
@@ -243,26 +234,21 @@ def _expected_exit(seed: int, r: int, graph: ModelGraph) -> int:
        mb=st.integers(min_value=1, max_value=4))
 def test_exit_conservation_and_determinism(exit_prob, seed, mb):
     """Every request exits at a declared head or the tail (counts sum to
-    n), the exit column matches the direct seeded recomputation, and the
-    two cores agree bit-for-bit."""
+    n) and the exit column matches the direct seeded recomputation."""
     g = branched_graph(exit_prob=round(exit_prob, 3))
     heads = {i for i, l in enumerate(g.layers) if l.exit_prob > 0.0}
     n = 60
     expect = np.array([_expected_exit(seed, r, g) for r in range(n)])
-    reps = {}
-    for core in ("heap", "fast"):
-        d = DistributedInference(make_paper_cluster(), ModelPartitioner(g),
-                                 method="planner")
-        rep = d.run(n, seed=seed, concurrency=4,
-                    engine=EngineConfig(micro_batch=mb, core=core))
-        assert set(np.unique(rep.columns.exit_head)) <= heads | {-1}
-        counts = rep.exit_counts()
-        assert sum(counts.values()) == n
-        assert np.array_equal(rep.columns.exit_head, expect)
-        assert rep.early_exit_rate == pytest.approx(
-            float(np.mean(expect >= 0)))
-        reps[core] = rep
-    assert reps["heap"].columns.bitwise_equal(reps["fast"].columns)
+    d = DistributedInference(make_paper_cluster(), ModelPartitioner(g),
+                             method="planner")
+    rep = d.run(n, seed=seed, concurrency=4,
+                engine=EngineConfig(micro_batch=mb))
+    assert set(np.unique(rep.columns.exit_head)) <= heads | {-1}
+    counts = rep.exit_counts()
+    assert sum(counts.values()) == n
+    assert np.array_equal(rep.columns.exit_head, expect)
+    assert rep.early_exit_rate == pytest.approx(
+        float(np.mean(expect >= 0)))
 
 
 def test_exit_draw_is_event_order_independent():
@@ -357,59 +343,56 @@ def test_join_waits_for_slowest_predecessor_bit_exact():
     """One request through the branched plan on idle distinct nodes:
     each stage starts at the max over predecessor arrivals (end +
     per-edge transfer), and the engine's finish time equals that forward
-    recomputation float-for-float — on both cores."""
-    finishes = []
-    for core in ("heap", "fast"):
-        d = _branched_pipeline()
-        rep = d.run(1, concurrency=1, engine=EngineConfig(core=core))
-        table = StageTable(d, 0)
-        S = len(table.stages)
-        assert not table.chain
-        from repro.core.scheduler import SCHEDULING_OVERHEAD_MS
-        arrive = [None] * S
-        # the paper's per-request scheduling decision precedes stage 0
-        arrive[0] = float(rep.columns.submit_ms[0]) + SCHEDULING_OVERHEAD_MS
-        end = [None] * S
-        for si in range(S):
-            assert arrive[si] is not None, f"stage {si} never fed"
-            end[si] = arrive[si] + table.stages[si].exec_ms
-            for e in (table.stages[si].succs or ()):
-                a = end[si] + e.xfer_ms
-                j = e.next_index
-                arrive[j] = a if arrive[j] is None else max(arrive[j], a)
-        # the join genuinely waited: the asymmetric arms arrive apart
-        assert arrive[3] > min(end[1] + table.stages[1].succs[0].xfer_ms,
-                               end[2] + table.stages[2].succs[0].xfer_ms)
-        assert float(rep.columns.finish_ms[0]) == end[S - 1], (
-            f"core {core}: finish {float(rep.columns.finish_ms[0])!r} != "
-            f"recomputed {end[S - 1]!r}")
-        finishes.append(end[S - 1])
-    assert finishes[0] == finishes[1]
+    recomputation float-for-float."""
+    from repro.core.scheduler import SCHEDULING_OVERHEAD_MS
+    d = _branched_pipeline()
+    rep = d.run(1, concurrency=1)
+    table = StageTable(d, 0)
+    S = len(table.stages)
+    assert not table.chain
+    arrive = [None] * S
+    # the paper's per-request scheduling decision precedes stage 0
+    arrive[0] = float(rep.columns.submit_ms[0]) + SCHEDULING_OVERHEAD_MS
+    end = [None] * S
+    for si in range(S):
+        assert arrive[si] is not None, f"stage {si} never fed"
+        end[si] = arrive[si] + table.stages[si].exec_ms
+        for e in (table.stages[si].succs or ()):
+            a = end[si] + e.xfer_ms
+            j = e.next_index
+            arrive[j] = a if arrive[j] is None else max(arrive[j], a)
+    # the join genuinely waited: the asymmetric arms arrive apart
+    assert arrive[3] > min(end[1] + table.stages[1].succs[0].xfer_ms,
+                           end[2] + table.stages[2].succs[0].xfer_ms)
+    assert float(rep.columns.finish_ms[0]) == end[S - 1], (
+        f"finish {float(rep.columns.finish_ms[0])!r} != "
+        f"recomputed {end[S - 1]!r}")
 
 
-# --- 4. fusion refusal + cascades ---------------------------------------------
+# --- 4. pinned branched run + cascades ----------------------------------------
+
+#: the branched run below: its event count and fingerprint, recorded where
+#: the heap loop and a since-removed time-wheel core (which had to refuse
+#: its chain fusion on DAG tables) dispatched identical event streams
+BRANCHED_EVENTS = 278
+BRANCHED_GOLDEN = (BRANCHED_EVENTS, None, (
+    ('', 50, 0, 0, 216781.168, 1292.60515, 9699328.0, None,
+     ((1, 86), (2, 19))),))
+
 
 def test_fast_core_event_count_pins_to_oracle_on_branched_plan():
-    """The fast core's chain fusion must refuse DAG tables: on a
-    branched plan both cores dispatch the exact same event stream (equal
-    counts) and produce bit-identical reports."""
+    """A branched plan with early exits and 2-way micro-batching
+    dispatches exactly the golden event count and reproduces its golden
+    fingerprint (counts, finish-time sum, p99 sojourn, network bytes,
+    batch histogram)."""
     g = branched_graph(exit_prob=0.3)
-    out = {}
-    for core in ("heap", "fast"):
-        eng_mod.LAST_EVENT_COUNT = None
-        fastcore.LAST_EVENT_COUNT = None
-        d = DistributedInference(make_paper_cluster(), ModelPartitioner(g),
-                                 method="planner")
-        rep = d.run(50, seed=7, concurrency=4,
-                    engine=EngineConfig(micro_batch=2, core=core))
-        out[core] = (rep, eng_mod.LAST_EVENT_COUNT if core == "heap"
-                     else fastcore.LAST_EVENT_COUNT)
-    heap_rep, heap_ev = out["heap"]
-    fast_rep, fast_ev = out["fast"]
-    assert heap_ev is not None and heap_ev > 0
-    assert heap_ev == fast_ev
-    assert heap_rep.columns.bitwise_equal(fast_rep.columns)
-    assert heap_rep.network_bytes == fast_rep.network_bytes
+    eng_mod.LAST_EVENT_COUNT = None
+    d = DistributedInference(make_paper_cluster(), ModelPartitioner(g),
+                             method="planner")
+    rep = d.run(50, seed=7, concurrency=4,
+                engine=EngineConfig(micro_batch=2))
+    assert eng_mod.LAST_EVENT_COUNT == BRANCHED_EVENTS
+    assert fingerprint(rep, eng_mod.LAST_EVENT_COUNT) == BRANCHED_GOLDEN
 
 
 def _cascade_registry(graph_cheap, n=120):
@@ -428,40 +411,26 @@ def _cascade_registry(graph_cheap, n=120):
 def test_cascade_escalates_exactly_the_misses():
     """Two-model cascade: every cheap-tenant request that runs to the
     tail (no exit head fired) re-enters the expensive tenant at its
-    finish time; the expensive tenant serves exactly those — and both
-    cores agree bit-for-bit."""
-    results = {}
-    for core in ("heap", "fast"):
-        res = _cascade_registry(branched_graph(exit_prob=0.6)).run(
-            engine=EngineConfig(core=core))
-        cheap, big = res.reports["cheap"], res.reports["big"]
-        miss = cheap.columns.exit_head == -1
-        assert int(miss.sum()) == len(big.columns) > 0
-        assert len(big.columns) < len(cheap.columns)
-        # escalations enter the big tenant at the cheap finish times
-        assert np.array_equal(np.sort(big.columns.submit_ms),
-                              np.sort(cheap.columns.finish_ms[miss]))
-        assert (big.columns.exit_head == -1).all()
-        results[core] = res
-    for name in ("cheap", "big"):
-        assert results["heap"].reports[name].columns.bitwise_equal(
-            results["fast"].reports[name].columns)
-    assert (results["heap"].goodput_rps()
-            == pytest.approx(results["fast"].goodput_rps()))
+    finish time; the expensive tenant serves exactly those."""
+    res = _cascade_registry(branched_graph(exit_prob=0.6)).run()
+    cheap, big = res.reports["cheap"], res.reports["big"]
+    miss = cheap.columns.exit_head == -1
+    assert int(miss.sum()) == len(big.columns) > 0
+    assert len(big.columns) < len(cheap.columns)
+    # escalations enter the big tenant at the cheap finish times
+    assert np.array_equal(np.sort(big.columns.submit_ms),
+                          np.sort(cheap.columns.finish_ms[miss]))
+    assert (big.columns.exit_head == -1).all()
 
 
 def test_cascade_with_no_misses_is_an_error():
     """A cascade whose target receives zero escalations is a
     misconfiguration (the expensive tenant's stream would be empty) and
-    must fail loudly, identically on both cores."""
+    must fail loudly."""
     g = branched_graph(exit_prob=0.999)   # virtually everything exits
-    msgs = []
-    for core in ("heap", "fast"):
-        with pytest.raises(RuntimeError) as ei:
-            _cascade_registry(g, n=20).run(engine=EngineConfig(core=core))
-        msgs.append(str(ei.value))
-    assert msgs[0] == msgs[1]
-    assert "escalated" in msgs[0]
+    with pytest.raises(RuntimeError) as ei:
+        _cascade_registry(g, n=20).run()
+    assert "escalated" in str(ei.value)
 
 
 def test_dag_restrictions_are_enforced():

@@ -1,16 +1,22 @@
 """Event-driven pipeline engine: parity with the legacy loop, transfer
-overlap, micro-batching, table invalidation, and the cache satellites."""
+overlap, micro-batching, table invalidation, the cache satellites, and
+oracle-free properties of the event loop (conservation, per-node FIFO
+order, goodput bounded by offered load, determinism)."""
+
+import random
 
 import numpy as np
 import pytest
 
 from repro.core.cache import ResultCache, digest
-from repro.core.cluster import make_paper_cluster
+from repro.core.cluster import make_paper_cluster, make_synthetic_cluster
 from repro.core.engine import EngineConfig, PipelineEngine, StageTable
 from repro.core.adaptation import (cpu_throttle, latency_spike, node_death,
                                    node_recovery)
 from repro.core.partitioner import ModelPartitioner
 from repro.core.pipeline import DistributedInference, run_monolithic
+from repro.core.tenancy import TenantRegistry, TenantTraffic
+from repro.core.traffic import DeterministicArrivals, PoissonArrivals
 from repro.models.graph import mobilenetv2_graph
 
 CONCURRENCY = 4          # closed-loop window for the scenario runs
@@ -382,3 +388,160 @@ def test_run_monolithic_deployer_assignment_consistent(graph):
     d = DistributedInference(cluster, ModelPartitioner(graph),
                              num_partitions=1, assignment=["edge-2-low"])
     assert d.deployer.assignment() == d.placement == {0: "edge-2-low"}
+
+
+# --- oracle-free properties of the event loop ---------------------------------
+
+#: each property holds for one stream alone and for three open-loop
+#: tenants interleaved on the same fleet
+STREAMS = ("single", "tenants3")
+
+
+def _property_reports(graph, stream, arrivals=None, n=120, seed=0,
+                      concurrency=6, **cfg_kw):
+    """Per-stream reports of one event-loop run on a 6-node fleet.
+    ``arrivals(i)`` gives tenant i's arrival process (``None`` keeps the
+    single stream closed-loop); ``"tenants3"`` registers three tenants
+    with seeds ``seed + i`` on one shared heap."""
+    cluster = make_synthetic_cluster(6, seed=3)
+    cfg = EngineConfig(**cfg_kw)
+    if stream == "single":
+        pipe = DistributedInference(cluster, ModelPartitioner(graph),
+                                    num_partitions=3, method="planner")
+        return [pipe.run(n, repeat_rate=0.2, seed=seed,
+                         concurrency=concurrency, engine=cfg,
+                         arrivals=arrivals(0) if arrivals else None)]
+    reg = TenantRegistry(cluster)
+    for i in range(3):
+        reg.add(f"t{i}", ModelPartitioner(graph),
+                traffic=TenantTraffic(num_requests=n, repeat_rate=0.2,
+                                      seed=seed + i, concurrency=concurrency,
+                                      arrivals=arrivals(i)),
+                num_partitions=3, method="planner")
+    return list(reg.run(engine=cfg).reports.values())
+
+
+@pytest.mark.parametrize("stream", STREAMS)
+def test_request_conservation(graph, stream):
+    """Every request finishes, after its submit, which follows its
+    arrival; every request runs every stage once (batch histogram)."""
+    arrivals = (None if stream == "single" else
+                lambda i: PoissonArrivals(rate_rps=3.0, seed=7 + i))
+    for rep in _property_reports(graph, stream, arrivals, n=150,
+                                 micro_batch=4, adaptive_batch=True,
+                                 transfer="overlap"):
+        c = rep.columns
+        assert len(c) == 150
+        assert np.all(c.finish_ms > 0)              # every request finished
+        assert np.all(c.finish_ms >= c.submit_ms)
+        assert np.all(c.submit_ms >= c.arrival_ms)
+        assert sum(k * v for k, v in rep.batch_hist.items()) % 150 == 0
+
+
+@pytest.mark.parametrize("stream", STREAMS)
+def test_per_node_fifo_order(graph, stream):
+    """k=1 FIFO queues: each stream's requests leave each stage in
+    submit order, so finish times are non-decreasing in request index —
+    also when other tenants' requests share the queues."""
+    for rep in _property_reports(
+            graph, stream, lambda i: DeterministicArrivals.at_rate(2.0),
+            n=100):
+        assert np.all(np.diff(rep.columns.finish_ms) >= 0)
+        assert np.all(np.diff(rep.columns.submit_ms) >= 0)
+
+
+@pytest.mark.parametrize("stream", STREAMS)
+def test_goodput_not_above_offered_load(graph, stream):
+    """Completions per simulated second cannot exceed the offered arrival
+    rate: the makespan extends at least to the last arrival."""
+    for rep in _property_reports(
+            graph, stream,
+            lambda i: PoissonArrivals(rate_rps=5.0, seed=11 + i), n=200):
+        c = rep.columns
+        makespan_s = (c.finish_ms.max() - c.arrival_ms.min()) / 1000.0
+        goodput = len(c) / makespan_s
+        offered = len(c) / ((c.arrival_ms.max() - c.arrival_ms.min())
+                            / 1000.0)
+        assert goodput <= offered * (1 + 1e-9)
+
+
+def _scramble_global_rngs(seed: int) -> None:
+    random.seed(seed)
+    np.random.seed(seed % 1000)
+    _ = [random.random() for _ in range(37)] + [np.random.random()]
+
+
+@pytest.mark.parametrize("stream", STREAMS)
+def test_determinism_under_global_rng_scrambling(graph, stream):
+    """The engine draws randomness only from explicitly seeded
+    generators: scrambling the global RNGs between runs changes nothing."""
+    def run():
+        return _property_reports(
+            graph, stream,
+            lambda i: PoissonArrivals(rate_rps=4.0, seed=2 + i), n=150,
+            micro_batch=4, transfer="serial")
+
+    _scramble_global_rngs(1234)
+    a = run()
+    _scramble_global_rngs(987654)
+    b = run()
+    for ra, rb in zip(a, b):
+        assert ra.columns.bitwise_equal(rb.columns)
+        assert ra.batch_hist == rb.batch_hist
+        assert ra.network_bytes == rb.network_bytes
+
+
+def _sliced_registry(graph, adaptive: bool):
+    """Three tenants on disjoint 4-node slices of a 12-node fleet, open
+    loop, with a CPU throttle on one of tenant 0's nodes at 2 s."""
+    cluster = make_synthetic_cluster(12, seed=77)
+    nids = list(cluster.nodes)
+    reg = TenantRegistry(cluster)
+    for i in range(3):
+        reg.add(f"t{i}", ModelPartitioner(graph),
+                traffic=TenantTraffic(
+                    num_requests=60, seed=5 + i, concurrency=8,
+                    arrivals=PoissonArrivals(rate_rps=8.0, seed=11 + i)),
+                num_partitions=3, method="planner", adaptive=adaptive,
+                nodes=nids[4 * i:4 * i + 4])
+    victim = reg.tenants["t0"].pipeline.placement[0]
+    return reg, [cpu_throttle(2000.0, victim, cpu=0.2)]
+
+
+@pytest.mark.parametrize("adaptive", (False, True),
+                         ids=("free-running", "adaptive"))
+def test_interleaved_tenants_are_deterministic(graph, adaptive):
+    """Three tenants interleaved on one heap, with or without adaptation
+    controllers (and their arbiter), reproduce every report field on a
+    repeat run from fresh state: columns, batch histograms, queue-depth
+    series, adaptation logs, monitor overhead and the arbitration
+    summary. Every placement stays inside its tenant's ``nodes=`` slice,
+    migrations included."""
+    def run():
+        reg, scenario = _sliced_registry(graph, adaptive)
+        res = reg.run(scenario=scenario,
+                      engine=EngineConfig(transfer="overlap", micro_batch=4,
+                                          adaptive_batch=True))
+        for t in reg.tenants.values():
+            assert set(t.placement.values()) <= t.pipeline.allowed_nodes
+        return res
+
+    _scramble_global_rngs(4321)
+    a = run()
+    _scramble_global_rngs(8765)
+    b = run()
+    assert set(a.reports) == set(b.reports) == {"t0", "t1", "t2"}
+    for name, ra in a.reports.items():
+        rb = b.reports[name]
+        assert ra.columns.bitwise_equal(rb.columns), name
+        assert ra.batch_hist == rb.batch_hist, name
+        assert ra.network_bytes == rb.network_bytes, name
+        assert np.array_equal(ra.queue_depth[0], rb.queue_depth[0]), name
+        assert np.array_equal(ra.queue_depth[1], rb.queue_depth[1]), name
+        assert ra.adaptation == rb.adaptation, name
+        assert ra.monitor_overhead_pct == rb.monitor_overhead_pct, name
+        assert ra.stability == rb.stability, name
+        assert (ra.adaptation is not None) == adaptive, name
+    assert a.arbitration == b.arbitration
+    if adaptive:   # the throttle made tenant 0's controller migrate
+        assert a.reports["t0"].adaptation["migrations"] >= 1
