@@ -70,7 +70,8 @@ HOST_REF_TOL = 5e-2
 PREFILL_TOL = 5e-2
 #: kernel vs oracle: flash takes bf16 q/k/v; SSD takes f32 operands whose
 #: in-kernel matmuls may run bf16 passes; RG-LRU is elementwise f32
-KERNEL_TOLS = {"flash": 2e-2, "flash_window": 2e-2, "ssd": 1e-2, "rglru": 1e-4}
+KERNEL_TOLS = {"flash": 2e-2, "flash_window": 2e-2, "flash_mla": 2e-2, "ssd": 1e-2,
+               "rglru": 1e-4}
 
 
 def _check(ok: bool, msg: str) -> None:
@@ -122,10 +123,14 @@ def _peak_gb() -> str:
 def kernel_shapes(seq: int = 2048) -> dict:
     """Kernel problem sizes at the widths of the configs that use them."""
     q = get_config("qwen2.5-3b")
+    ds = get_config("deepseek-v2-236b")
     m = get_config("mamba2-130m")
     _, heads, groups, _ = ssm_dims(m)
     return dict(
         flash=(1, q.num_heads, q.num_kv_heads, seq, q.head_dim_),
+        # MLA: every head has its own k and v, q.k over nope + rope widths
+        flash_mla=(1, ds.num_heads, ds.num_heads, seq,
+                   ds.qk_nope_head_dim + ds.qk_rope_head_dim, ds.v_head_dim),
         ssd=(1, seq, heads, m.ssm_head_dim, groups, m.ssm_state, m.ssm_chunk),
         rglru=(1, seq, lru_width(get_config("recurrentgemma-9b")), 256),
     )
@@ -146,16 +151,20 @@ def phase_kernels(shapes: dict, impl: str = "pallas", seed: int = 0) -> dict:
               f"{warm * 1e3:.3f} ms, err {errs[name]:.3e} (tol {KERNEL_TOLS[name]:g})")
         _check(errs[name] <= KERNEL_TOLS[name], f"kernel {name} off its oracle")
 
-    b, hq, hkv, s, d = shapes["flash"]
-    q = jax.random.normal(ks[0], (b, hq, s, d), jnp.float32).astype(jnp.bfloat16)
-    k = jax.random.normal(ks[1], (b, hkv, s, d), jnp.float32).astype(jnp.bfloat16)
-    v = jax.random.normal(ks[2], (b, hkv, s, d), jnp.float32).astype(jnp.bfloat16)
-    for name, window in (("flash", 0), ("flash_window", s // 4)):
+    def attention(name, shape, key, window):
+        b, hq, hkv, s, d, *dv = shape
+        kq, kk, kv = jax.random.split(key, 3)
+        q = jax.random.normal(kq, (b, hq, s, d), jnp.float32).astype(jnp.bfloat16)
+        k = jax.random.normal(kk, (b, hkv, s, d), jnp.float32).astype(jnp.bfloat16)
+        v = jax.random.normal(kv, (b, hkv, s, *(dv or [d])), jnp.float32).astype(jnp.bfloat16)
         run(name,
-            lambda q, k, v, w=window: ops.attention(q, k, v, causal=True, window=w,
-                                                    impl=impl),
-            lambda q, k, v, w=window: ref.attention_ref(q, k, v, causal=True, window=w),
+            lambda q, k, v: ops.attention(q, k, v, causal=True, window=window, impl=impl),
+            lambda q, k, v: ref.attention_ref(q, k, v, causal=True, window=window),
             q, k, v)
+
+    attention("flash", shapes["flash"], ks[0], 0)
+    attention("flash_window", shapes["flash"], ks[0], shapes["flash"][3] // 4)
+    attention("flash_mla", shapes["flash_mla"], ks[1], 0)
 
     b, length, h, p, g, n, chunk = shapes["ssd"]
     x = jax.random.normal(ks[3], (b, length, h, p), jnp.float32) * 0.5

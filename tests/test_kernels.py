@@ -24,9 +24,13 @@ ATTN_SHAPES = [
     (1, 2, 2, 128, 64),
     (2, 4, 2, 256, 64),    # GQA 2:1
     (1, 8, 1, 256, 128),   # MQA
-    (2, 2, 2, 384, 32),    # three blocks
-    (1, 4, 2, 200, 64),    # seq not a multiple of the block: a ragged last block
+    (2, 2, 2, 384, 32),    # the whole sequence one block
+    (1, 4, 2, 200, 64),    # one block of the whole, not a multiple of 128
     (1, 2, 2, 256, 192, 128),   # MLA (DeepSeek-V2): q.k over 128 + 64, v 128
+    # longer than one chosen block: several q and k blocks, pairs skipped
+    (1, 2, 1, 1024, 128),  # GQA 2:1, two blocks of 512
+    (1, 1, 1, 1024, 192, 128),  # MLA at DeepSeek-V2's prompt
+    (1, 2, 1, 1100, 64),   # three blocks, the last ragged
 ]
 
 
@@ -38,6 +42,29 @@ def test_flash_attention_vs_ref(shape, causal, window):
                    b, hq, hkv, s, d, jnp.float32, *dv)
     out_ref = ref.attention_ref(q, k, v, causal=causal, window=window)
     out = flash_attention(q, k, v, causal=causal, window=window, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("block_q,block_k", [
+    (128, 256), (256, 128),
+    # off the TPU's tiling, for interpret mode only: a k block that ends one
+    # past a q block's first row, and a q block whose last row is the first
+    # of a k block
+    (96, 98), (99, 98),
+])
+@pytest.mark.parametrize("causal,window", [
+    (True, 0), (True, 64), (False, 0),
+    (True, 130), (False, 127),   # window edges one off a block boundary
+])
+def test_flash_attention_unequal_blocks(block_q, block_k, causal, window):
+    # the skip's block arithmetic where q and k blocks differ; 685 positions
+    # leave every last block ragged, the 98-blocks one short of full
+    q, k, v = _qkv(jax.random.PRNGKey(block_q + 3 * window + causal),
+                   1, 2, 1, 685, 64, jnp.float32)
+    out_ref = ref.attention_ref(q, k, v, causal=causal, window=window)
+    out = flash_attention(q, k, v, causal=causal, window=window,
+                          block_q=block_q, block_k=block_k, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
                                rtol=2e-5, atol=2e-5)
 

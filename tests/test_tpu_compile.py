@@ -35,6 +35,17 @@ def _flash(window):
                 ((1, 2, 2048, 128), jnp.bfloat16)]
 
 
+def _flash_mla():
+    # DeepSeek-V2 prefill block: 8 sequences of 1024, 128 heads, q.k 192, v 128
+    from repro.kernels import ops
+
+    def fn(q, k, v):
+        return ops.attention(q, k, v, causal=True, impl="pallas")
+    return fn, [((8, 128, 1024, 192), jnp.bfloat16),
+                ((8, 128, 1024, 192), jnp.bfloat16),
+                ((8, 128, 1024, 128), jnp.bfloat16)]
+
+
 def _ssd():
     # mamba2-130m: d_inner 1536 = 24 heads x 64, state 128, one group, chunk 256
     from repro.kernels import ops
@@ -59,6 +70,7 @@ def _rglru():
 CASES = {
     "flash_causal": lambda: _flash(0),
     "flash_window": lambda: _flash(512),
+    "flash_mla": _flash_mla,
     "ssd_mamba2_130m": _ssd,
     "rglru_w4096": _rglru,
 }
