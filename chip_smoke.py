@@ -6,7 +6,8 @@ to the process that opened it) and fails the run if its check fails:
 
 1. kernels: each Pallas kernel (flash attention, SSD scan, RG-LRU scan) at
    the widths of the model that uses it, against its ``kernels/ref.py``
-   oracle evaluated at highest matmul precision.
+   oracle evaluated at highest matmul precision; the SSD scan also at
+   Granite-4.0-H's prefill block (``prefill_rows`` sequences of 8192).
 2. partitioned: MobileNetV2 at 224x224x3 / 1000 classes, cut by the DP
    planner over the paper's three-node cluster. A seeded batch is served
    through ``DistributedInference.infer`` with every stage on the chip,
@@ -71,7 +72,7 @@ PREFILL_TOL = 5e-2
 #: kernel vs oracle: flash takes bf16 q/k/v; SSD takes f32 operands whose
 #: in-kernel matmuls may run bf16 passes; RG-LRU is elementwise f32
 KERNEL_TOLS = {"flash": 2e-2, "flash_window": 2e-2, "flash_mla": 2e-2, "ssd": 1e-2,
-               "rglru": 1e-4}
+               "ssd_granite": 1e-2, "rglru": 1e-4}
 
 
 def _check(ok: bool, msg: str) -> None:
@@ -126,12 +127,17 @@ def kernel_shapes(seq: int = 2048) -> dict:
     ds = get_config("deepseek-v2-236b")
     m = get_config("mamba2-130m")
     _, heads, groups, _ = ssm_dims(m)
+    g = get_config("granite-4.0-h-small")
+    _, g_heads, g_groups, _ = ssm_dims(g)
     return dict(
         flash=(1, q.num_heads, q.num_kv_heads, seq, q.head_dim_),
         # MLA: every head has its own k and v, q.k over nope + rope widths
         flash_mla=(1, ds.num_heads, ds.num_heads, seq,
                    ds.qk_nope_head_dim + ds.qk_rope_head_dim, ds.v_head_dim),
         ssd=(1, seq, heads, m.ssm_head_dim, groups, m.ssm_state, m.ssm_chunk),
+        # the served prompt of the Granite cell, one prefill block of rows
+        ssd_granite=(Model(g).prefill_rows(32, 8192), 8192, g_heads, g.ssm_head_dim,
+                     g_groups, g.ssm_state, g.ssm_chunk),
         rglru=(1, seq, lru_width(get_config("recurrentgemma-9b")), 256),
     )
 
@@ -166,13 +172,15 @@ def phase_kernels(shapes: dict, impl: str = "pallas", seed: int = 0) -> dict:
     attention("flash_window", shapes["flash"], ks[0], shapes["flash"][3] // 4)
     attention("flash_mla", shapes["flash_mla"], ks[1], 0)
 
-    b, length, h, p, g, n, chunk = shapes["ssd"]
-    x = jax.random.normal(ks[3], (b, length, h, p), jnp.float32) * 0.5
-    dt = jax.nn.softplus(jax.random.normal(ks[4], (b, length, h))) * 0.1
-    a = -jnp.exp(jax.random.normal(ks[5], (h,)) * 0.5)
-    bm, cm = jax.random.normal(ks[6], (2, b, length, g, n)) * 0.3
-    run("ssd", lambda *t: ops.ssd(*t, chunk=chunk, impl=impl), ref.ssd_sequential,
-        x, dt, a, bm, cm)
+    for name in ("ssd", "ssd_granite"):
+        b, length, h, p, g, n, chunk = shapes[name]
+        k3, k4, k5, k6 = (jax.random.fold_in(k, name == "ssd_granite") for k in ks[3:7])
+        x = jax.random.normal(k3, (b, length, h, p), jnp.float32) * 0.5
+        dt = jax.nn.softplus(jax.random.normal(k4, (b, length, h))) * 0.1
+        a = -jnp.exp(jax.random.normal(k5, (h,)) * 0.5)
+        bm, cm = jax.random.normal(k6, (2, b, length, g, n)) * 0.3
+        run(name, lambda *t, chunk=chunk: ops.ssd(*t, chunk=chunk, impl=impl),
+            ref.ssd_sequential, x, dt, a, bm, cm)
 
     b, length, w, chunk = shapes["rglru"]
     ka, kb = jax.random.split(ks[7])

@@ -17,6 +17,7 @@ _REGISTRY: Dict[str, str] = {
     "kimi-k2-1t-a32b": "kimi_k2_1t_a32b",
     "deepseek-v2-236b": "deepseek_v2_236b",
     "recurrentgemma-9b": "recurrentgemma_9b",
+    "granite-4.0-h-small": "granite_4_0_h_small",
     "whisper-medium": "whisper_medium",
     "llama-3.2-vision-90b": "llama_3_2_vision_90b",
 }

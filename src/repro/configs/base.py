@@ -4,6 +4,12 @@ One ``ModelConfig`` instance fully describes an architecture; the model
 builders in ``repro.models`` consume it.  ``reduced()`` produces the
 smoke-test variant (2 layers, d_model <= 512, <= 4 experts) of the same
 family, as required for CPU tests.
+
+The ``hybrid`` family repeats one period of blocks (``block_pattern``),
+each a mixer and an FFN: the mixer is an RG-LRU (``"rec"``), a Mamba-2
+SSD mixer (``"mamba"``) or attention (``"attn"``: local over
+``local_window`` positions, or global where it is 0); the FFN is an MoE
+where ``num_experts`` is set, else the dense MLP.
 """
 
 from __future__ import annotations
@@ -37,12 +43,13 @@ class ModelConfig:
     source: str = ""                 # citation for the config
 
     # --- attention ---
-    rope_theta: float = 10000.0
+    rope_theta: float = 10000.0      # 0: no position embedding (NoPE)
     rotary_pct: float = 1.0          # chatglm "2d rope": rotary on half the dims
     qkv_bias: bool = False
     attn_variant: str = "full"       # full | sliding  (sliding enables long_500k)
     window: int = 8192               # sliding-window size
     attn_logit_cap: float = 0.0
+    attention_multiplier: float = 0.0    # softmax scale; 0 -> 1/sqrt(head_dim)
     kv_cache_dtype: str = "model"    # "model" (= cfg dtype) | "int8" (quantized
                                      # per-(pos, head) with f32 scales — halves
                                      # decode HBM traffic; GQA caches only)
@@ -84,11 +91,13 @@ class ModelConfig:
     ssm_head_dim: int = 64
     ssm_chunk: int = 256
     ssm_ngroups: int = 1
+    ssm_conv_bias: bool = False
 
-    # --- hybrid (recurrentgemma) ---
+    # --- hybrid (recurrentgemma, granite-4.0-h) ---
+    # one period of block kinds: "rec" (RG-LRU), "mamba" (Mamba-2), "attn"
     block_pattern: Tuple[str, ...] = ()   # e.g. ("rec", "rec", "attn")
     lru_width: int = 0               # 0 -> d_model
-    local_window: int = 2048
+    local_window: int = 2048         # 0: global attention over a cache_len cache
 
     # --- encoder-decoder (whisper backbone) ---
     encoder_layers: int = 0
@@ -104,6 +113,11 @@ class ModelConfig:
     act: str = "silu"                # silu (SwiGLU) | gelu (plain MLP)
     norm: str = "rmsnorm"            # rmsnorm | layernorm
     tie_embeddings: bool = False
+    # muP-style multipliers (granite): embeddings times, each residual
+    # branch times, logits divided by
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     dtype: str = "bfloat16"
     # long_500k support: "native" (ssm/hybrid), "sliding" (dense w/ window), "skip"
     long_context: str = "sliding"
@@ -159,8 +173,10 @@ class ModelConfig:
         if self.ssm_state:
             kw.update(ssm_state=32, ssm_head_dim=32, ssm_chunk=64)
         if self.block_pattern:
-            # keep both block kinds present in the 2-layer smoke variant
-            kw.update(block_pattern=("rec", "attn"), lru_width=0, local_window=128)
+            # one block of each kind of the pattern, in the order they appear
+            kinds = tuple(dict.fromkeys(self.block_pattern))
+            kw.update(num_layers=len(kinds), block_pattern=kinds, lru_width=0,
+                      local_window=min(self.local_window, 128))
         if self.encoder_layers:
             kw.update(encoder_layers=2, num_frames=64, max_positions=512)
         if self.cross_attn_every:
@@ -181,7 +197,7 @@ class ModelConfig:
             assert self.top_k <= self.topk_group * self.num_experts // self.n_group
             assert self.first_expert_held + self.num_experts_held <= self.num_experts
         if self.block_pattern:
-            assert set(self.block_pattern) <= {"rec", "attn"}
+            assert set(self.block_pattern) <= {"rec", "mamba", "attn"}
 
 
 def asdict(cfg: ModelConfig) -> dict:

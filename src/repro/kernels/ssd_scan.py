@@ -130,5 +130,6 @@ def ssd_scan(
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",     # the op's name in a profile (bench/metrics/ssd_roofline.py)
     )(xt, dtt, a.astype(jnp.float32), bt, ct)
     return jnp.swapaxes(y, 1, 2), h

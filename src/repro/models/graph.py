@@ -298,7 +298,7 @@ def transformer_graph(cfg: ModelConfig, batch: int = 1, seq: int = 2048) -> Mode
         kind = "attn"
         if cfg.family == "hybrid":
             kind = cfg.block_pattern[i % len(cfg.block_pattern)]
-        if cfg.family == "ssm":
+        if cfg.family == "ssm" or kind == "mamba":
             kind = "ssm"
 
         if cfg.family == "vlm" and (i + 1) % cfg.cross_attn_every == 0:
@@ -342,7 +342,7 @@ def transformer_graph(cfg: ModelConfig, batch: int = 1, seq: int = 2048) -> Mode
         # FFN sublayer
         if cfg.family == "ssm":
             continue  # mamba block has no separate FFN
-        is_moe = cfg.family == "moe" and i >= cfg.first_dense_layers
+        is_moe = cfg.num_experts > 0 and i >= cfg.first_dense_layers
         if is_moe:
             pe = cfg.num_experts * 3 * D * cfg.d_ff_expert
             pa = cfg.top_k * 3 * D * cfg.d_ff_expert \

@@ -123,6 +123,11 @@ def init_attention(b: ParamBuilder, name: str, cfg: ModelConfig,
         sub.param("b_v", (nkv * hd,), ("kv_heads",), init="zeros")
 
 
+def softmax_scale(cfg: ModelConfig) -> Optional[float]:
+    """The config's ``attention_multiplier``, else None: 1/sqrt(head_dim)."""
+    return cfg.attention_multiplier or None
+
+
 def _project_qkv(p, x, cfg: ModelConfig, nh: int, nkv: int):
     B, S, _ = x.shape
     hd = cfg.head_dim_
@@ -160,7 +165,7 @@ def apply_attention(
     qh = q.transpose(0, 2, 1, 3)
     kh = k.transpose(0, 2, 1, 3)
     vh = v.transpose(0, 2, 1, 3)
-    o = ops.attention(qh, kh, vh, causal=causal, window=window)
+    o = ops.attention(qh, kh, vh, causal=causal, window=window, scale=softmax_scale(cfg))
     o = o.transpose(0, 2, 1, 3).reshape(x.shape[0], x.shape[1], -1)
     o = shard(o, "batch", None, "heads")
     return o @ p["w_o"], (kh, vh)
@@ -240,7 +245,7 @@ def apply_attention_decode(
     else:
         valid = idx <= pos
     mask = jnp.broadcast_to(valid[None, :], (B, s_cache))
-    o = ops.decode_attention(qh, k_use, v_use, mask)
+    o = ops.decode_attention(qh, k_use, v_use, mask, scale=softmax_scale(cfg))
     o = o.transpose(0, 2, 1, 3).reshape(B, 1, nh * hd)
     out = o @ p["w_o"]
     if cache_scales is not None:
@@ -309,12 +314,16 @@ def init_embed(b: ParamBuilder, cfg: ModelConfig):
 
 def embed_tokens(params, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
     x = params["embed"].astype(cfg.jnp_dtype)[tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     return shard(x, "batch", None, None)
 
 
 def unembed(params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = x @ w.astype(x.dtype)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     if cfg.padded_vocab != cfg.vocab_size:  # mask padding tail
         pad_mask = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
         logits = jnp.where(pad_mask, logits, jnp.asarray(-1e30, logits.dtype))

@@ -13,6 +13,14 @@ One ``Model`` object per ``ModelConfig`` exposes:
 Layer stacks are scanned over stacked params (HLO stays small at 61–100
 layers); hybrid/vlm scan over repeating super-blocks. Remat is applied per
 block in training via ``jax.checkpoint``.
+
+A ``hybrid`` super-block is one period of ``block_pattern``: each block is
+norm -> mixer (RG-LRU, Mamba-2 or attention, local or global) -> residual
+(times ``residual_multiplier``), then norm -> FFN (an MoE where the config
+has experts, else the dense MLP) -> residual. Its decode cache holds each
+block's state side by side under explicit names (``_STATE``): the conv
+window and recurrent state of RG-LRU and Mamba-2 blocks, the k/v of
+attention blocks.
 """
 
 from __future__ import annotations
@@ -36,9 +44,24 @@ from jax.ad_checkpoint import checkpoint_name as _checkpoint_name
 
 Pytree = Any
 
-# the most that the expanded per-head q, k and v of one block of a prefill
+# the most that the widest per-token temporary of one block of a prefill
 # may take (``Model.prefill_rows``)
 PREFILL_BLOCK_BYTES = 1 << 30
+
+# the decode-cache entries of one hybrid block, by kind: ``s{j}_<name>``
+# (stacked over super-blocks) or ``t{t}_<name>`` (a tail block)
+_STATE = {"rec": ("conv", "h"), "mamba": ("conv", "ssm"), "attn": ("k", "v")}
+
+
+def _state_names(prefix: str, kind: str) -> Tuple[str, ...]:
+    return tuple(f"{prefix}_{n}" for n in _STATE[kind])
+
+
+def is_recurrent_state(name: str) -> bool:
+    """Whether a decode-cache entry is a recurrent state or a conv window,
+    whose size does not grow with the context (``conv``, ``ssm``,
+    ``s{j}_h``, ...), rather than k/v, latents or counters."""
+    return name.rsplit("_", 1)[-1] in ("conv", "h", "ssm")
 
 
 # ---------------------------------------------------------------------------
@@ -67,10 +90,15 @@ def _init_hybrid_block(b: ParamBuilder, cfg: ModelConfig, kind: str):
     L.init_norm(b, "ln1", cfg.d_model, cfg.norm)
     if kind == "rec":
         RG.init_rglru(b, "mixer", cfg)
+    elif kind == "mamba":
+        SSM.init_ssm(b, "mixer", cfg)
     else:
         L.init_attention(b, "attn", cfg)
     L.init_norm(b, "ln2", cfg.d_model, cfg.norm)
-    L.init_mlp(b, "ffn", cfg)
+    if cfg.num_experts:
+        MOE.init_moe(b, "ffn", cfg)
+    else:
+        L.init_mlp(b, "ffn", cfg)
 
 
 def _init_cross_block(b: ParamBuilder, cfg: ModelConfig, gated: bool):
@@ -253,27 +281,75 @@ class Model:
         attn_out = shard(attn_out, "batch", "seq", None)
         x = x + _checkpoint_name(attn_out, "blk_out")
         h = L.apply_norm(p["ln2"], x, cfg.norm, cfg.norm_eps)
-        counts = None
-        if use_moe and collect_kv:
-            ffn_out, counts = MOE.serve_moe(p["ffn"], h, cfg, impl=self.moe_impl)
-            aux = jnp.zeros((1,), jnp.float32)
-        elif use_moe:
-            ffn_out, aux = MOE.apply_moe(p["ffn"], h, cfg, impl=self.moe_impl)
-        else:
-            ffn_out, aux = L.apply_mlp(p["ffn"], h, cfg), jnp.zeros((1,), jnp.float32)
+        ffn_out, aux, counts = self._ffn(p["ffn"], h, use_moe=use_moe, serving=collect_kv)
         ffn_out = shard(ffn_out, "batch", "seq", None)
         x = x + _checkpoint_name(ffn_out, "blk_out")
         x = shard(x, "batch", "seq", None)
         return x, aux, (kv if collect_kv else None), counts
 
-    def _window(self, shape_kind: str) -> int:
-        """Attention window for a given execution (0 = full)."""
+    def _ffn(self, p, h, *, use_moe: bool, serving: bool):
+        """A block's FFN: the MoE where ``use_moe`` (serving drops nothing
+        and gives its counts; training clips at capacity and gives the aux
+        loss), else the dense MLP. Returns (out, aux (1,) or per token,
+        counts (3,) or None)."""
         cfg = self.cfg
-        if cfg.family == "hybrid":
-            return cfg.local_window
-        if shape_kind == "long" and cfg.long_context == "sliding":
-            return cfg.window
-        return 0
+        if not use_moe:
+            return L.apply_mlp(p, h, cfg), jnp.zeros((1,), jnp.float32), None
+        if serving:
+            out, counts = MOE.serve_moe(p, h, cfg, impl=self.moe_impl)
+            return out, jnp.zeros((1,), jnp.float32), counts
+        out, aux = MOE.apply_moe(p, h, cfg, impl=self.moe_impl)
+        return out, aux, None
+
+    def _branch(self, out):
+        """A residual branch's output times ``residual_multiplier``."""
+        m = self.cfg.residual_multiplier
+        return out if m == 1.0 else out * jnp.asarray(m, out.dtype)
+
+    def _hybrid_block(self, p, kind: str, x, positions, *, serving: bool):
+        """One block of a hybrid period over the full sequence. Returns (x,
+        aux, the block's decode state in ``_STATE`` order, MoE counts or
+        None); ``serving`` (a prefill) runs the experts dropless."""
+        cfg = self.cfg
+        h = L.apply_norm(p["ln1"], x, cfg.norm, cfg.norm_eps)
+        if kind == "rec":
+            out, st = RG.apply_rglru(p["mixer"], h, cfg)
+            state = (st["conv"], st["h"])
+        elif kind == "mamba":
+            out, st = SSM.apply_ssm(p["mixer"], h, cfg)
+            state = (st["conv"], st["ssm"])
+        else:
+            out, kv = L.apply_attention(p["attn"], h, cfg, positions, causal=True,
+                                        window=cfg.local_window)
+            state = self._clip_window_kv(kv, x.shape[1]) if cfg.local_window else kv
+        x = x + self._branch(out)
+        h = L.apply_norm(p["ln2"], x, cfg.norm, cfg.norm_eps)
+        f, aux, counts = self._ffn(p["ffn"], h, use_moe=bool(cfg.num_experts),
+                                   serving=serving)
+        return x + self._branch(f), aux, state, counts
+
+    def _hybrid_block_decode(self, p, kind: str, x, state, pos):
+        """One block of a hybrid period for one token against its cache
+        entries ``state`` (``_STATE`` order). Returns (x, the new entries,
+        MoE counts or None)."""
+        cfg = self.cfg
+        h = L.apply_norm(p["ln1"], x, cfg.norm, cfg.norm_eps)
+        if kind == "rec":
+            out, st = RG.apply_rglru_decode(p["mixer"], h, cfg,
+                                            {"conv": state[0], "h": state[1]})
+            state = (st["conv"], st["h"])
+        elif kind == "mamba":
+            out, st = SSM.apply_ssm_decode(p["mixer"], h, cfg,
+                                           {"conv": state[0], "ssm": state[1]})
+            state = (st["conv"], st["ssm"])
+        else:
+            out, nk, nv = L.apply_attention_decode(p["attn"], h, cfg, state[0], state[1],
+                                                   pos, window=cfg.local_window)
+            state = (nk, nv)
+        x = x + self._branch(out)
+        h = L.apply_norm(p["ln2"], x, cfg.norm, cfg.norm_eps)
+        f, _, counts = self._ffn(p["ffn"], h, use_moe=bool(cfg.num_experts), serving=True)
+        return x + self._branch(f), state, counts
 
     # -- training / prefill forward -----------------------------------------
 
@@ -283,9 +359,11 @@ class Model:
 
         batch: {"tokens": (B, S) int32 [, "frames": (B, F, D), "images": (B, I, D)]}
         Returns (logits (B, S, V), aux_loss scalar, cache_or_None).
-        mode: "train" (logits over all positions) or "prefill" (also returns
-        cache, and MoE layers drop nothing and give their counts as
-        ``cache["moe_counts"]`` (layers, 3)).
+        mode: "train" (logits over all positions) or "prefill" (the last
+        position's logits, and the decode state of the S positions under
+        ``init_cache``'s names where the model ``can_prefill``: k/v or
+        latents of S positions, recurrent states and conv windows; MoE
+        layers drop nothing and give their counts as ``moe_counts`` (n, 3)).
         """
         cfg = self.cfg
         tokens = batch["tokens"]
@@ -328,17 +406,21 @@ class Model:
                     return (x, aux + a.mean()), (kv if counts is None else (kv, counts))
                 return body
 
+            kv_d = ()
             if fd:
                 (x, aux_total), kv_d = self._scan(
                     maybe_remat(mk_body(False)), (x, aux_total), params["dense_blocks"])
-                if collect:
-                    caches["dense_kv"] = kv_d
             (x, aux_total), kv_m = self._scan(
                 maybe_remat(mk_body(cfg.family == "moe")), (x, aux_total), params["blocks"])
-            if collect and cfg.family == "moe":
-                caches["kv"], caches["moe_counts"] = kv_m
-            elif collect:
-                caches["kv"] = kv_m
+            if collect:
+                counts = None
+                if cfg.family == "moe":
+                    kv_m, counts = kv_m
+                names = ("ckv", "krope") if cfg.use_mla else ("k", "v")
+                caches.update(zip(names, kv_m))
+                caches.update(zip(("d_" + n for n in names), kv_d))
+                if counts is not None:
+                    caches["moe_counts"] = counts
 
         elif cfg.family == "ssm":
             def body(carry, p):
@@ -348,48 +430,39 @@ class Model:
                 return x + out, st if collect else None
             x, states = self._scan(maybe_remat(body), x, params["blocks"])
             if collect:
-                caches["ssm_states"] = states
+                caches.update(states)
 
         elif cfg.family == "hybrid":
+            counts = []
+
             def super_body(carry, p):
-                x = carry
-                st_out = {}
+                x, aux = carry
+                states, cs = {}, []
                 for j, kind in enumerate(self._pattern):
-                    bp = p[f"b{j}_{kind}"]
-                    h = L.apply_norm(bp["ln1"], x, cfg.norm, cfg.norm_eps)
-                    if kind == "rec":
-                        out, st = RG.apply_rglru(bp["mixer"], h, cfg)
-                        if collect:
-                            st_out[f"b{j}"] = st
-                    else:
-                        out, kv = L.apply_attention(
-                            bp["attn"], h, cfg, positions, causal=True,
-                            window=cfg.local_window)
-                        if collect:
-                            st_out[f"b{j}"] = self._clip_window_kv(kv, S)
-                    x = x + out
-                    h = L.apply_norm(bp["ln2"], x, cfg.norm, cfg.norm_eps)
-                    x = x + L.apply_mlp(bp["ffn"], h, cfg)
+                    x, a, st, c = self._hybrid_block(p[f"b{j}_{kind}"], kind, x, positions,
+                                                     serving=collect)
+                    aux = aux + a.mean()
+                    states.update(zip(_state_names(f"s{j}", kind), st))
+                    if c is not None:
+                        cs.append(c)
                 x = shard(x, "batch", "seq", None)
-                return x, (st_out if collect else None)
-            x, sup_states = self._scan(maybe_remat(super_body), x, params["super"])
+                return (x, aux), ((states, jnp.stack(cs) if cs else None) if collect else None)
+            (x, aux_total), sup = self._scan(
+                maybe_remat(super_body), (x, aux_total), params["super"])
             if collect:
-                caches["super"] = sup_states
+                caches.update(sup[0])
+                if sup[1] is not None:
+                    counts.append(sup[1].reshape(-1, 3))
             for t in range(self._n_tail):
                 kind = self._pattern[t % len(self._pattern)]
-                bp = params[f"tail{t}"]
-                h = L.apply_norm(bp["ln1"], x, cfg.norm, cfg.norm_eps)
-                if kind == "rec":
-                    out, st = RG.apply_rglru(bp["mixer"], h, cfg)
-                else:
-                    out, kv = L.apply_attention(bp["attn"], h, cfg, positions,
-                                                causal=True, window=cfg.local_window)
-                    st = self._clip_window_kv(kv, S)
-                if collect:
-                    caches[f"tail{t}"] = st
-                x = x + out
-                h = L.apply_norm(bp["ln2"], x, cfg.norm, cfg.norm_eps)
-                x = x + L.apply_mlp(bp["ffn"], h, cfg)
+                x, a, st, c = self._hybrid_block(params[f"tail{t}"], kind, x, positions,
+                                                 serving=collect)
+                aux_total = aux_total + a.mean()
+                caches.update(zip(_state_names(f"t{t}", kind), st))
+                if c is not None:
+                    counts.append(c[None])
+            if collect and counts:
+                caches["moe_counts"] = jnp.concatenate(counts)
 
         elif cfg.family == "audio":
             memory = self._encode(params, batch["frames"])
@@ -452,7 +525,8 @@ class Model:
         return logits, aux_total, None
 
     def _clip_window_kv(self, kv, S):
-        """Keep only the trailing window of prefill K/V for the local cache."""
+        """The prefill's K/V laid out as the local cache's ring buffer of
+        ``local_window`` slots: position p in slot p % window."""
         w = self.cfg.local_window
         k, v = kv
         if S < w:
@@ -460,7 +534,8 @@ class Model:
             k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
             v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
         elif S > w:
-            k, v = k[:, :, -w:, :], v[:, :, -w:, :]
+            k = jnp.roll(k[:, :, -w:, :], S % w, axis=2)
+            v = jnp.roll(v[:, :, -w:, :], S % w, axis=2)
         return (k, v)
 
     def _encode(self, params, frames: jax.Array) -> jax.Array:
@@ -565,27 +640,29 @@ class Model:
                 ("layers", "batch", "heads", None, None), jnp.float32)
         elif cfg.family == "hybrid":
             W = RG.lru_width(cfg)
-            win = cfg.local_window
+            di, H, _, d_bc = SSM.ssm_dims(cfg)
+            win = cfg.local_window or cache_len
+            # per kind: the shape, logical axes and dtype of each entry
+            # (``_STATE`` order) of one block, without the layer axis
+            kv = ((batch, cfg.num_kv_heads, win, hd), ("batch", "kv_heads", "kv_seq", None), dt)
+            entries_of = {
+                "rec": (((batch, RG._CONV_K - 1, W), ("batch", None, "ff"), dt),
+                        ((batch, W), ("batch", "ff"), jnp.float32)),
+                "mamba": (((batch, cfg.ssm_conv - 1, di + 2 * d_bc), ("batch", None, "ff"), dt),
+                          ((batch, H, cfg.ssm_head_dim, cfg.ssm_state),
+                           ("batch", "heads", None, None), jnp.float32)),
+                "attn": (kv, kv),
+            }
             for j, kind in enumerate(self._pattern):
-                if kind == "rec":
-                    put(f"s{j}_conv", (self._n_super, batch, RG._CONV_K - 1, W),
-                        ("layers", "batch", None, "ff"))
-                    put(f"s{j}_h", (self._n_super, batch, W),
-                        ("layers", "batch", "ff"), jnp.float32)
-                else:
-                    kvs = ("layers", "batch", "kv_heads", "kv_seq", None)
-                    put(f"s{j}_k", (self._n_super, batch, cfg.num_kv_heads, win, hd), kvs)
-                    put(f"s{j}_v", (self._n_super, batch, cfg.num_kv_heads, win, hd), kvs)
+                for name, (shape, axes, dtype) in zip(_state_names(f"s{j}", kind),
+                                                      entries_of[kind]):
+                    put(name, (self._n_super,) + shape, ("layers",) + axes, dtype)
             for t in range(self._n_tail):
                 kind = self._pattern[t % len(self._pattern)]
-                if kind == "rec":
-                    put(f"t{t}_conv", (batch, RG._CONV_K - 1, W), ("batch", None, "ff"))
-                    put(f"t{t}_h", (batch, W), ("batch", "ff"), jnp.float32)
-                else:
-                    put(f"t{t}_k", (batch, cfg.num_kv_heads, win, hd),
-                        ("batch", "kv_heads", "kv_seq", None))
-                    put(f"t{t}_v", (batch, cfg.num_kv_heads, win, hd),
-                        ("batch", "kv_heads", "kv_seq", None))
+                for name, entry in zip(_state_names(f"t{t}", kind), entries_of[kind]):
+                    put(name, *entry)
+            if cfg.num_experts:
+                put("moe_counts", (3,), (None,), jnp.int32)
         elif cfg.family == "audio":
             kvs = ("layers", "batch", "kv_heads", "kv_seq", None)
             n = cfg.num_layers
@@ -615,23 +692,36 @@ class Model:
     def can_prefill(self) -> bool:
         """Whether ``prefill`` fills the cache that stepping ``decode_step``
         through the prompt would: a dense or MoE decoder whose cache is a
-        plain k/v or MLA latent cache. MoE layers drop no token on either
-        path, so the full-sequence pass routes each token as a step does.
-        Elsewhere the cache has another layout (int8) or holds recurrent or
-        cross state."""
+        plain k/v or MLA latent cache, a Mamba-2 stack, or a hybrid of
+        Mamba-2 and attention blocks (the chunked scan's final state and the
+        last conv inputs are what the steps carry). MoE layers drop no token
+        on either path, so the full-sequence pass routes each token as a
+        step does. Elsewhere the cache has another layout (int8) or holds
+        RG-LRU or cross state."""
         cfg = self.cfg
-        return cfg.family in ("dense", "moe") and cfg.kv_cache_dtype != "int8"
+        if cfg.family == "hybrid":
+            return "rec" not in self._pattern
+        return cfg.family == "ssm" or (cfg.family in ("dense", "moe")
+                                       and cfg.kv_cache_dtype != "int8")
 
     def prefill_rows(self, batch: int, prompt_len: int) -> int:
         """Sequences per block of the prefill: the most, dividing ``batch``,
-        whose per-head q, k and v take at most ``PREFILL_BLOCK_BYTES``
-        (MLA expands its latent to 128 heads of 192 + 192 + 128)."""
+        whose widest per-token temporary takes at most
+        ``PREFILL_BLOCK_BYTES``: the per-head q, k and v (MLA expands its
+        latent to 128 heads of 192 + 192 + 128), the Mamba-2
+        in-projection, or the expert dispatch operand of top_k rows of
+        ``d_model`` a token."""
         cfg = self.cfg
         if cfg.use_mla:
             width = cfg.num_heads * (2 * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
                                      + cfg.v_head_dim)
         else:
             width = (cfg.num_heads + 2 * cfg.num_kv_heads) * cfg.head_dim_
+        if cfg.ssm_state:
+            di, H, _, d_bc = SSM.ssm_dims(cfg)
+            width = max(width, 2 * di + 2 * d_bc + H)
+        if cfg.num_experts:
+            width = max(width, cfg.top_k * cfg.d_model)
         per_row = prompt_len * width * cfg.jnp_dtype.itemsize
         rows = batch
         while rows > 1 and (batch % rows or rows * per_row > PREFILL_BLOCK_BYTES):
@@ -643,19 +733,22 @@ class Model:
 
         Returns (logits of the last position (B, V), the decode cache of
         ``cache_len`` slots that ``init_cache`` describes, with the prompt's
-        k/v or latents in slots [0, P), zeros after them, ``pos`` = P and,
-        for MoE, the prompt's counts). Blocks of ``prefill_rows`` sequences
-        go through one after another inside the call, each writing its rows
-        of the cache. Only where ``can_prefill``."""
+        k/v or latents in slots [0, P), zeros after them, recurrent states
+        and conv windows after P positions, ``pos`` = P and, for MoE, the
+        prompt's counts). Blocks of ``prefill_rows`` sequences go through
+        one after another inside the call, each writing its rows of the
+        cache. Only where ``can_prefill``."""
         if not self.can_prefill:
-            raise ValueError(f"{self.cfg.name}: prefill needs a dense or MoE decoder "
-                             "with a plain k/v or latent cache")
+            raise ValueError(f"{self.cfg.name}: prefill needs a decoder whose cache is "
+                             "plain k/v, latents or Mamba-2 state")
         B, P = tokens.shape
         if cache_len < P:
             raise ValueError(f"cache of {cache_len} slots for a prompt of {P}")
         rows = self.prefill_rows(B, P)
         if rows == B:
             return self._prefill_block(params, tokens, cache_len)
+
+        cache, axes = self.init_cache(B, cache_len)
 
         def block(i, carry):
             logits, cache = carry
@@ -664,32 +757,30 @@ class Model:
             logits = jax.lax.dynamic_update_slice_in_dim(logits, lg, i * rows, 0)
             cache = {name: (MOE.add_counts(a, part[name][None]) if name == "moe_counts"
                             else a if name == "pos"
-                            else jax.lax.dynamic_update_slice_in_dim(a, part[name],
-                                                                     i * rows, 1))
+                            else jax.lax.dynamic_update_slice_in_dim(
+                                a, part[name], i * rows, axes[name].index("batch")))
                      for name, a in cache.items()}
             return logits, cache
 
-        cache, _ = self.init_cache(B, cache_len)
         cache["pos"] = jnp.asarray(P, jnp.int32)
         logits = jnp.zeros((B, self.cfg.padded_vocab), self.cfg.jnp_dtype)
         return jax.lax.fori_loop(0, B // rows, block, (logits, cache))
 
     def _prefill_block(self, params, tokens: jax.Array, cache_len: int):
-        """``prefill`` of one block of sequences in one pass."""
-        cfg = self.cfg
-        P = tokens.shape[1]
-        logits, _, kv = self.forward(params, {"tokens": tokens}, mode="prefill")
-        dt = cfg.jnp_dtype
-        if cfg.use_mla:       # (layers, B, P, width)
-            names, pad = ("ckv", "krope"), ((0, 0), (0, 0), (0, cache_len - P), (0, 0))
-        else:                 # (layers, B, heads, P, head_dim)
-            names, pad = ("k", "v"), ((0, 0), (0, 0), (0, 0), (0, cache_len - P), (0, 0))
-        cache = dict(zip(names, (jnp.pad(a.astype(dt), pad) for a in kv["kv"])))
-        if "dense_kv" in kv:
-            cache.update(zip(("d_" + n for n in names),
-                             (jnp.pad(a.astype(dt), pad) for a in kv["dense_kv"])))
-        if "moe_counts" in kv:
-            cache["moe_counts"] = MOE.add_counts(jnp.zeros((3,), jnp.int32), kv["moe_counts"])
+        """``prefill`` of one block of sequences in one pass: the forward's
+        states in the cache's types, each k/v or latent padded with zeros
+        from P to ``cache_len`` slots."""
+        B, P = tokens.shape
+        logits, _, states = self.forward(params, {"tokens": tokens}, mode="prefill")
+        want, _ = self.init_cache(B, cache_len, abstract=True)
+        cache = {}
+        for name, a in states.items():
+            if name == "moe_counts":
+                cache[name] = MOE.add_counts(jnp.zeros((3,), jnp.int32), a)
+            else:
+                w = want[name]
+                cache[name] = jnp.pad(a.astype(w.dtype),
+                                      [(0, n - m) for m, n in zip(a.shape, w.shape)])
         cache["pos"] = jnp.asarray(P, jnp.int32)
         return logits, cache
 
@@ -767,48 +858,34 @@ class Model:
             new_cache["conv"], new_cache["ssm"] = nconv, nssm
 
         elif cfg.family == "hybrid":
+            blocks = [(j, kind, _state_names(f"s{j}", kind))
+                      for j, kind in enumerate(self._pattern)]
+
             def super_body(x, sl):
-                p = sl[0]
-                cslices = sl[1]
-                outs = {}
-                for j, kind in enumerate(self._pattern):
-                    bp = p[f"b{j}_{kind}"]
-                    h = L.apply_norm(bp["ln1"], x, cfg.norm, cfg.norm_eps)
-                    if kind == "rec":
-                        out, st = RG.apply_rglru_decode(
-                            bp["mixer"], h, cfg,
-                            {"conv": cslices[f"s{j}_conv"], "h": cslices[f"s{j}_h"]})
-                        outs[f"s{j}_conv"], outs[f"s{j}_h"] = st["conv"], st["h"]
-                    else:
-                        out, nk, nv = L.apply_attention_decode(
-                            bp["attn"], h, cfg, cslices[f"s{j}_k"], cslices[f"s{j}_v"],
-                            pos, window=cfg.local_window)
-                        outs[f"s{j}_k"], outs[f"s{j}_v"] = nk, nv
-                    x = x + out
-                    h = L.apply_norm(bp["ln2"], x, cfg.norm, cfg.norm_eps)
-                    x = x + L.apply_mlp(bp["ffn"], h, cfg)
-                return x, outs
-            sup_cache = {k: cache[k] for k in cache
-                         if k.startswith("s") and not k.startswith("ssm")}
-            x, new_sup = self._scan(super_body, x, (params["super"], sup_cache))
+                p, c = sl
+                outs, cs = {}, []
+                for j, kind, names in blocks:
+                    x, st, cnt = self._hybrid_block_decode(
+                        p[f"b{j}_{kind}"], kind, x, tuple(c[n] for n in names), pos)
+                    outs.update(zip(names, st))
+                    if cnt is not None:
+                        cs.append(cnt)
+                return x, (outs, jnp.stack(cs) if cs else None)
+            sup_cache = {n: cache[n] for _, _, names in blocks for n in names}
+            x, (new_sup, counts) = self._scan(super_body, x, (params["super"], sup_cache))
             new_cache.update(new_sup)
+            counts = [] if counts is None else [counts.reshape(-1, 3)]
             for t in range(self._n_tail):
                 kind = self._pattern[t % len(self._pattern)]
-                bp = params[f"tail{t}"]
-                h = L.apply_norm(bp["ln1"], x, cfg.norm, cfg.norm_eps)
-                if kind == "rec":
-                    out, st = RG.apply_rglru_decode(
-                        bp["mixer"], h, cfg,
-                        {"conv": cache[f"t{t}_conv"], "h": cache[f"t{t}_h"]})
-                    new_cache[f"t{t}_conv"], new_cache[f"t{t}_h"] = st["conv"], st["h"]
-                else:
-                    out, nk, nv = L.apply_attention_decode(
-                        bp["attn"], h, cfg, cache[f"t{t}_k"], cache[f"t{t}_v"],
-                        pos, window=cfg.local_window)
-                    new_cache[f"t{t}_k"], new_cache[f"t{t}_v"] = nk, nv
-                x = x + out
-                h = L.apply_norm(bp["ln2"], x, cfg.norm, cfg.norm_eps)
-                x = x + L.apply_mlp(bp["ffn"], h, cfg)
+                names = _state_names(f"t{t}", kind)
+                x, st, cnt = self._hybrid_block_decode(
+                    params[f"tail{t}"], kind, x, tuple(cache[n] for n in names), pos)
+                new_cache.update(zip(names, st))
+                if cnt is not None:
+                    counts.append(cnt[None])
+            if counts:
+                new_cache["moe_counts"] = MOE.add_counts(cache["moe_counts"],
+                                                         jnp.concatenate(counts))
 
         elif cfg.family == "audio":
             x = x + params["dec_pos"].astype(x.dtype)[pos][None, None, :]

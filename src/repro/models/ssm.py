@@ -1,9 +1,11 @@
 """Mamba-2 block (SSD, arXiv:2405.21060).
 
-Projections -> causal depthwise conv on (x, B, C) -> chunked SSD scan
-(Pallas kernel on TPU, chunked jnp elsewhere) -> gated RMSNorm -> out proj.
-Decode carries (conv window, SSM state) — O(1) per token, which is what
-makes ``long_500k`` native for this family.
+Projections -> causal depthwise conv on (x, B, C), with a bias where
+``ssm_conv_bias`` -> chunked SSD scan (Pallas kernel on TPU, chunked jnp
+elsewhere) -> gated RMSNorm -> out proj. Decode carries (conv window, SSM
+state) — O(1) per token, which is what makes ``long_500k`` native for this
+family. A full-sequence pass ends with the same two, so a prefill leaves
+them in the decode cache.
 """
 
 from __future__ import annotations
@@ -41,6 +43,8 @@ def init_ssm(b: ParamBuilder, name: str, cfg: ModelConfig):
     sub.param("conv_x", (K, d_inner), (None, "ff"), scale=0.5)
     sub.param("conv_b", (K, d_bc), (None, None), scale=0.5)
     sub.param("conv_c", (K, d_bc), (None, None), scale=0.5)
+    if cfg.ssm_conv_bias:       # over (x, B, C), in that order
+        sub.param("conv_bias", (d_inner + 2 * d_bc,), (None,), init="zeros")
     sub.param("norm", (d_inner,), (None,), init="ones", dtype=jnp.float32)
     sub.param("w_out", (d_inner, cfg.d_model), ("ff", None))
 
@@ -60,8 +64,12 @@ def _causal_depthwise(x: jax.Array, w: jax.Array, init_state: jax.Array | None =
     return y, new_state
 
 
-def apply_ssm(p, x: jax.Array, cfg: ModelConfig, state=None):
-    """Full-sequence SSD. x: (B, L, D). Returns (out, (conv_state, ssm_state))."""
+def apply_ssm(p, x: jax.Array, cfg: ModelConfig):
+    """Full-sequence SSD from a zero state. x: (B, L, D). Returns (out,
+    {"conv": the last ``ssm_conv - 1`` conv inputs, "ssm": the final state})
+    — what ``apply_ssm_decode`` carries after the same L positions. A length
+    above the chunk and not a multiple of it is padded with dt = 0, which
+    leaves the state as it was."""
     B, L, D = x.shape
     d_inner, H, G, d_bc = ssm_dims(cfg)
     P_dim = cfg.ssm_head_dim
@@ -76,8 +84,9 @@ def apply_ssm(p, x: jax.Array, cfg: ModelConfig, state=None):
 
     conv_in = jnp.concatenate([xs, bm, cm], axis=-1)
     conv_w = jnp.concatenate([p["conv_x"], p["conv_b"], p["conv_c"]], axis=-1)
-    cstate = None if state is None else state["conv"]
-    conv_out, new_conv = _causal_depthwise(conv_in, conv_w, cstate)
+    conv_out, new_conv = _causal_depthwise(conv_in, conv_w)
+    if "conv_bias" in p:
+        conv_out = conv_out + p["conv_bias"]
     conv_out = jax.nn.silu(conv_out)
     xs = conv_out[..., :d_inner]
     bm = conv_out[..., d_inner : d_inner + d_bc]
@@ -90,11 +99,14 @@ def apply_ssm(p, x: jax.Array, cfg: ModelConfig, state=None):
     bmr = bm.reshape(B, L, G, N)
     cmr = cm.reshape(B, L, G, N)
     chunk = min(cfg.ssm_chunk, L)
-    y, h_fin = ops.ssd(xh, dt, a, bmr, cmr, chunk=chunk)
-    if state is not None:
-        # fold carried SSM state into the first chunk's output: exact only for
-        # prefill-from-scratch; decode uses apply_ssm_decode instead.
-        raise NotImplementedError("use apply_ssm_decode for stateful stepping")
+    pad = -L % chunk
+    if pad:
+        def tail(t):
+            return jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        y, h_fin = ops.ssd(tail(xh), tail(dt), a, tail(bmr), tail(cmr), chunk=chunk)
+        y = y[:, :L]
+    else:
+        y, h_fin = ops.ssd(xh, dt, a, bmr, cmr, chunk=chunk)
     y = y + p["d_skip"].astype(y.dtype)[None, None, :, None] * xh
     y = y.reshape(B, L, d_inner)
     y = y * jax.nn.silu(z)
@@ -122,7 +134,10 @@ def apply_ssm_decode(p, x: jax.Array, cfg: ModelConfig, state):
     conv_in = jnp.concatenate([xs, bm, cm], axis=-1)          # (B, C)
     window = jnp.concatenate([state["conv"], conv_in[:, None, :]], axis=1)  # (B, K, C)
     conv_w = jnp.concatenate([p["conv_x"], p["conv_b"], p["conv_c"]], axis=-1)
-    conv_out = jax.nn.silu(jnp.einsum("bkc,kc->bc", window, conv_w))
+    conv_out = jnp.einsum("bkc,kc->bc", window, conv_w)
+    if "conv_bias" in p:
+        conv_out = conv_out + p["conv_bias"]
+    conv_out = jax.nn.silu(conv_out)
     new_conv = window[:, 1:, :]
 
     xs = conv_out[:, :d_inner]

@@ -1,13 +1,15 @@
 """Batched serving engine with AMP4EC scheduling.
 
 Real greedy decoding (JAX) over model replicas "deployed" on simulated edge
-nodes: a dense or MoE decoder takes a group's prompt in one jitted prefill
-(``Model.prefill``), every other family steps ``decode_step`` through it one
-position at a time, and each new token is one ``decode_step``. The AMP4EC
-TaskScheduler (NSA) routes each batch to a replica, and node time is charged
-via a FLOPs-based edge cost model, so the serving metrics (TTFT, per-token
-latency, throughput, load distribution) reflect the paper's scheduling
-behaviour while numerics stay real.
+nodes: a model that ``can_prefill`` (dense or MoE decoders, Mamba-2, and
+hybrids of Mamba-2 and attention) takes a group's prompt in one jitted
+prefill (``Model.prefill``), every other family steps ``decode_step``
+through it one position at a time, and each new token is one
+``decode_step``. The AMP4EC TaskScheduler (NSA) routes each batch to a
+replica, and node time is charged via a FLOPs-based edge cost model, so
+the serving metrics (TTFT, per-token latency, throughput, load
+distribution) reflect the paper's scheduling behaviour while numerics
+stay real.
 
 The batcher groups requests by prompt length (uniform-position batches match
 the scalar-position cache layout used by the production decode path).
@@ -28,7 +30,7 @@ from repro.configs.base import ModelConfig
 from repro.core.cluster import EdgeCluster
 from repro.core.monitor import ResourceMonitor
 from repro.core.scheduler import SCHEDULING_OVERHEAD_MS, TaskRequirements, TaskScheduler
-from repro.models.model import Model
+from repro.models.model import Model, is_recurrent_state
 from repro.utils import obs
 
 EDGE_FLOPS_PER_CPU = 5e9  # effective flop/s per 1.0 edge CPU (serving cost model)
@@ -114,10 +116,13 @@ class ServingEngine:
         self._prefill_jit = jax.jit(self.model.prefill, static_argnums=2)
         self._flops_per_token = 2.0 * self.model.param_count(params)
 
-    def _cache_bytes(self, batch: int, slots: int) -> int:
-        """Device bytes of the decode cache of a group."""
+    def _cache_bytes(self, batch: int, slots: int) -> Tuple[int, int]:
+        """Device bytes of the decode cache of a group: all of it, and its
+        recurrent states and conv windows."""
         cache, _ = self.model.init_cache(batch, slots, abstract=True)
-        return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+        size = {name: a.size * a.dtype.itemsize for name, a in cache.items()}
+        return (sum(size.values()),
+                sum(n for name, n in size.items() if is_recurrent_state(name)))
 
     # --- batching -------------------------------------------------------------
 
@@ -138,8 +143,9 @@ class ServingEngine:
 
         ``amp4ec.prompt`` runs until the first generated token is on the
         host, so the device backlog of the prompt's work falls inside it;
-        it holds one ``amp4ec.prefill`` where the model ``can_prefill``,
-        else the teacher-forced steps. ``amp4ec.generate`` runs from there
+        it holds one ``amp4ec.prefill`` (its ``rows``, sequences a block,
+        and ``blocks``) where the model ``can_prefill``, else the
+        teacher-forced steps. ``amp4ec.generate`` runs from there
         until the last token is on the host. With the recorder on, each of
         the two carries the MoE counters of its passes (``COUNTS``)."""
         B = len(group)
@@ -149,7 +155,11 @@ class ServingEngine:
         out = []
         with obs.span("amp4ec.prompt") as prompt:
             if self.model.can_prefill:
-                with obs.span("amp4ec.prefill"):
+                attrs = {}
+                if obs.enabled():
+                    rows = self.model.prefill_rows(B, P)
+                    attrs = dict(rows=rows, blocks=B // rows)
+                with obs.span("amp4ec.prefill", **attrs):
                     logits, cache = self._prefill_jit(self.params, tokens, cache_len(P, N))
                 tok = self._sample(logits, out) if N else None
                 prompt.set(prefilled=B * P, stepped=0)
@@ -241,11 +251,13 @@ class ServingEngine:
                     if node_id is None:
                         node_id = min(self.cluster.online_nodes(),
                                       key=lambda n: n.busy_until_ms).node_id
-                attrs = (dict(batch=len(group), prompt_len=P, new_tokens=N,
-                              cache_len=cache_len(P, N), node=node_id,
-                              cache_bytes=self._cache_bytes(len(group), cache_len(P, N)),
-                              requests=[r.request_id for r in group])
-                         if obs.enabled() else {})
+                attrs = {}
+                if obs.enabled():
+                    total, state = self._cache_bytes(len(group), cache_len(P, N))
+                    attrs = dict(batch=len(group), prompt_len=P, new_tokens=N,
+                                 cache_len=cache_len(P, N), node=node_id,
+                                 cache_bytes=total, state_bytes=state,
+                                 requests=[r.request_id for r in group])
                 with obs.root("amp4ec.group", **attrs):
                     out = self._generate_group(group)
 

@@ -19,6 +19,7 @@ _spec.loader.exec_module(chip_smoke)
 TINY_KERNEL_SHAPES = dict(flash=(1, 4, 2, 256, 64),
                           flash_mla=(1, 2, 2, 256, 192, 128),
                           ssd=(1, 128, 2, 32, 1, 16, 64),
+                          ssd_granite=(1, 192, 4, 16, 1, 32, 64),
                           rglru=(1, 128, 1024, 64))
 
 
@@ -27,6 +28,7 @@ def test_kernel_shapes_are_model_widths():
     assert shapes["flash"] == (1, 16, 2, 2048, 128)         # qwen2.5-3b
     assert shapes["flash_mla"] == (1, 128, 128, 2048, 192, 128)  # deepseek-v2
     assert shapes["ssd"] == (1, 2048, 24, 64, 1, 128, 256)  # mamba2-130m
+    assert shapes["ssd_granite"] == (1, 8192, 128, 64, 1, 128, 256)  # granite-4.0-h-small
     assert shapes["rglru"] == (1, 2048, 4096, 256)          # recurrentgemma-9b
 
 

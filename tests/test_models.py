@@ -212,9 +212,10 @@ def test_int8_kv_cache_decode_close_to_fp(rng):
 
 
 # the configurations whose full-sequence pass fills the decode cache: dense
-# and MoE decoders with a plain k/v or MLA latent cache
-PREFILL_ARCHS = ("chatglm3-6b", "deepseek-v2-236b", "kimi-k2-1t-a32b", "qwen2.5-3b",
-                 "qwen2-7b", "yi-9b")
+# and MoE decoders with a plain k/v or MLA latent cache, and Mamba-2 (alone
+# or beside attention)
+PREFILL_ARCHS = ("chatglm3-6b", "deepseek-v2-236b", "granite-4.0-h-small",
+                 "kimi-k2-1t-a32b", "mamba2-130m", "qwen2.5-3b", "qwen2-7b", "yi-9b")
 
 
 @pytest.mark.parametrize("arch,prompt_len,impl", [
@@ -225,7 +226,8 @@ PREFILL_ARCHS = ("chatglm3-6b", "deepseek-v2-236b", "kimi-k2-1t-a32b", "qwen2.5-
 def test_prefill_then_decode_matches_stepped_decode(arch, prompt_len, impl, rng):
     """``prefill`` and K decode steps give the logits and greedy tokens of
     P - 1 teacher-forced steps and K + 1 more; its cache is the stepped
-    cache in slots [0, P), zeros after them, with ``pos`` = P."""
+    cache: k/v and latents in slots [0, P) and zeros after them, recurrent
+    states and conv windows whole, with ``pos`` = P."""
     from repro.kernels import ops
 
     cfg = dataclasses.replace(get_config(arch).reduced(), dtype="float32")
@@ -245,9 +247,14 @@ def test_prefill_then_decode_matches_stepped_decode(arch, prompt_len, impl, rng)
     for t in range(P):
         ref_logits, ref = step(params, tokens[:, t], ref)
 
+    _, axes = model.init_cache(B, cache_len, abstract=True)
     assert int(cache["pos"]) == P and set(cache) == set(ref)
     for name in set(cache) - {"pos", "moe_counts"}:
         assert cache[name].shape == ref[name].shape and cache[name].dtype == ref[name].dtype
+        if "kv_seq" not in axes[name]:           # state of the whole prompt
+            np.testing.assert_allclose(np.asarray(cache[name]), np.asarray(ref[name]),
+                                       rtol=1e-5, atol=1e-5)
+            continue
         np.testing.assert_allclose(np.asarray(cache[name][..., :P, :]),
                                    np.asarray(ref[name][..., :P, :]), rtol=1e-5, atol=1e-5)
         assert not np.asarray(cache[name][..., P:, :]).any()
@@ -265,10 +272,14 @@ def test_prefill_then_decode_matches_stepped_decode(arch, prompt_len, impl, rng)
 def test_can_prefill_is_dense_with_a_plain_kv_cache(arch):
     cfg = get_config(arch)
     assert Model(cfg).can_prefill == (arch in PREFILL_ARCHS)
+    # an int8 k/v cache has another layout; Mamba-2 and hybrid caches hold
+    # no quantized k/v, so the setting leaves them as they are
     int8 = Model(dataclasses.replace(cfg, kv_cache_dtype="int8"))
-    assert not int8.can_prefill
-    with pytest.raises(ValueError):
-        int8.prefill(None, jnp.zeros((1, 4), jnp.int32), 8)
+    assert int8.can_prefill == (arch in PREFILL_ARCHS and cfg.family in ("ssm", "hybrid"))
+    for model in (Model(cfg), int8):
+        if not model.can_prefill:
+            with pytest.raises(ValueError):
+                model.prefill(None, jnp.zeros((1, 4), jnp.int32), 8)
 
 
 def test_prefill_blocks_follow_the_expanded_attention():

@@ -135,22 +135,26 @@ def test_threads_keep_their_own_parents_and_lose_no_count():
 
 # --- what the program leaves behind ------------------------------------------
 
-@pytest.mark.parametrize("arch", ["qwen2.5-3b", "mamba2-130m"])
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "mamba2-130m", "recurrentgemma-9b",
+                                  "granite-4.0-h-small"])
 def test_serve_leaves_prompt_generate_steps_and_samples(arch, recording):
-    """A dense decoder's prompt is one ``amp4ec.prefill``; another
-    family's is P - 1 teacher-forced steps and the first token's step."""
+    """A dense decoder's, a Mamba-2 stack's or a Mamba-2 / attention
+    hybrid's prompt is one ``amp4ec.prefill`` (one block of both rows); an
+    RG-LRU hybrid's is P - 1 teacher-forced steps and the first token's
+    step. ``amp4ec.group`` gives the cache's bytes and those of its
+    recurrent state; an MoE model's phases carry its counters beside."""
     import jax
     from repro.configs import get_config
     from repro.core.cluster import make_paper_cluster
-    from repro.models.model import Model
+    from repro.models.model import Model, is_recurrent_state
     from repro.serving import Request, ServingEngine
-    from repro.serving.engine import measured_ms
+    from repro.serving.engine import COUNTS, measured_ms
 
     cfg = get_config(arch).reduced()
     params, _ = Model(cfg).init(jax.random.PRNGKey(0))
     engine = ServingEngine(cfg, params, make_paper_cluster(), max_batch=2)
     prefills = engine.model.can_prefill
-    assert prefills == (arch == "qwen2.5-3b")
+    assert prefills == (arch != "recurrentgemma-9b")
     P, N = 5, 4
     reqs = [Request(i, np.arange(1, P + 1, dtype=np.int32), N) for i in range(4)]
     obs.enable()                               # the set-up's spans go
@@ -160,9 +164,12 @@ def test_serve_leaves_prompt_generate_steps_and_samples(arch, recording):
     assert len(groups) == 2 and len(_named(snap, "amp4ec.serve")) == 1
     assert len(_named(snap, "amp4ec.schedule")) == 2
     cache, _ = engine.model.init_cache(2, P + N + 1)
+    state = sum(a.nbytes for name, a in cache.items() if is_recurrent_state(name))
     assert groups[0].attrs == dict(batch=2, prompt_len=P, new_tokens=N, cache_len=P + N + 1,
                                    node=reqs[0].node_id, requests=[0, 1],
-                                   cache_bytes=sum(a.nbytes for a in jax.tree.leaves(cache)))
+                                   cache_bytes=sum(a.nbytes for a in jax.tree.leaves(cache)),
+                                   state_bytes=state)
+    assert (state > 0) == (arch != "qwen2.5-3b")
     for g in groups:
         mine = [s for s in snap["spans"] if s.root_id == g.span_id and s is not g]
         count = {n: sum(s.name == n for s in mine)
@@ -175,8 +182,10 @@ def test_serve_leaves_prompt_generate_steps_and_samples(arch, recording):
         prompt, = (s for s in mine if s.name == "amp4ec.prompt")
         generate, = (s for s in mine if s.name == "amp4ec.generate")
         assert g.start <= prompt.start < prompt.end <= generate.start < generate.end <= g.end
-        assert prompt.attrs == (dict(prefilled=2 * P, stepped=0) if prefills
-                                else dict(prefilled=0, stepped=2 * P))
+        counters = {k: v for k, v in prompt.attrs.items() if k not in COUNTS}
+        assert counters == (dict(prefilled=2 * P, stepped=0) if prefills
+                            else dict(prefilled=0, stepped=2 * P))
+        assert (set(COUNTS) <= set(prompt.attrs)) == bool(cfg.num_experts)
         # the first token's sample ends the prompt phase
         assert sum(prompt.start <= s.start and s.end <= prompt.end
                    for s in mine if s.name == "amp4ec.sample") == 1
@@ -185,6 +194,9 @@ def test_serve_leaves_prompt_generate_steps_and_samples(arch, recording):
         first = min(s.start for s in mine if s.name == "amp4ec.sample")
         before = [s.name for s in mine if s.end <= first and s.name != "amp4ec.prompt"]
         assert before == (["amp4ec.prefill"] if prefills else ["amp4ec.step"] * P)
+        for s in mine:
+            if s.name == "amp4ec.prefill":
+                assert s.attrs == dict(rows=2, blocks=1)
     t = measured_ms(snap)
     group_ms = sum(g.end - g.start for g in groups) / 2 * 1e3
     assert 0 < t["route_ms"] and 0 < t["itl_ms"]

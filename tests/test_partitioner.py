@@ -128,7 +128,8 @@ def test_weighted_targets_shift_boundaries(n, w):
 
 @pytest.mark.parametrize("arch", ["qwen2-7b", "mamba2-130m", "kimi-k2-1t-a32b",
                                   "recurrentgemma-9b", "whisper-medium",
-                                  "llama-3.2-vision-90b", "deepseek-v2-236b"])
+                                  "llama-3.2-vision-90b", "deepseek-v2-236b",
+                                  "granite-4.0-h-small"])
 def test_transformer_graph_partitionable(arch):
     cfg = get_config(arch)
     g = transformer_graph(cfg, batch=1, seq=2048)

@@ -47,14 +47,23 @@ def test_prefill_and_stepped_prompt_serve_the_same_tokens(monkeypatch):
 
 
 @pytest.mark.parametrize("arch", ["recurrentgemma-9b", "mamba2-130m"])
-def test_moe_and_ssm_step_through_the_prompt(arch):
+def test_moe_and_ssm_step_through_the_prompt(arch, monkeypatch):
+    """An RG-LRU hybrid steps through the prompt. A Mamba-2 stack prefills
+    it, and serves the tokens that stepping through it serves."""
     cfg = f32_reduced(arch)
-    assert not Model(cfg).can_prefill
+    prefills = arch == "mamba2-130m"
+    assert Model(cfg).can_prefill == prefills
     params, _ = Model(cfg).init(jax.random.PRNGKey(0))
     P, N = 6, 3
     out, snap = _serve(cfg, params, (P, P), new_tokens=N)
     assert out.shape == (2 * N,)
-    assert _count(snap, "amp4ec.prefill") == 0
-    assert _count(snap, "amp4ec.step") == P + N - 1
+    assert _count(snap, "amp4ec.prefill") == int(prefills)
+    assert _count(snap, "amp4ec.step") == (N - 1 if prefills else P + N - 1)
     prompt, = (s for s in snap["spans"] if s.name == "amp4ec.prompt")
-    assert prompt.attrs == dict(prefilled=0, stepped=2 * P)
+    assert prompt.attrs == (dict(prefilled=2 * P, stepped=0) if prefills
+                            else dict(prefilled=0, stepped=2 * P))
+    if prefills:
+        monkeypatch.setattr(Model, "can_prefill", property(lambda self: False))
+        stepped, snap = _serve(cfg, params, (P, P), new_tokens=N)
+        assert _count(snap, "amp4ec.prefill") == 0
+        np.testing.assert_array_equal(out, stepped)

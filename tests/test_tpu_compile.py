@@ -58,6 +58,22 @@ def _ssd():
                 ((b, length, g, n), jnp.bfloat16)]
 
 
+def _ssd_granite():
+    # granite-4.0-h-small prefill block: prefill_rows(32, 8192) sequences of
+    # 8192, d_inner 8192 = 128 heads x 64, state 128, one group, chunk 256
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models.model import Model
+
+    def fn(*t):
+        return ops.ssd(*t, chunk=256, impl="pallas")
+    b = Model(get_config("granite-4.0-h-small")).prefill_rows(32, 8192)
+    length, h, p, g, n = 8192, 128, 64, 1, 128
+    return fn, [((b, length, h, p), jnp.bfloat16), ((b, length, h), jnp.float32),
+                ((h,), jnp.float32), ((b, length, g, n), jnp.bfloat16),
+                ((b, length, g, n), jnp.bfloat16)]
+
+
 def _rglru():
     # recurrentgemma-9b: LRU width = d_model = 4096, f32 recurrence, chunk 256
     from repro.kernels import ops
@@ -72,6 +88,7 @@ CASES = {
     "flash_window": lambda: _flash(512),
     "flash_mla": _flash_mla,
     "ssd_mamba2_130m": _ssd,
+    "ssd_granite_prefill": _ssd_granite,
     "rglru_w4096": _rglru,
 }
 
